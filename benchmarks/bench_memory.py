@@ -8,6 +8,12 @@ the paper's memory/partition trade-off), plus the host-side peak
 the full population on the host between dispatches, so host footprint
 is part of the per-worker budget.  derived = per-device bytes.
 
+The child runs on the CPU backend (``JAX_PLATFORMS=cpu``) whatever the
+parent runs on: on a TPU host the parent may already hold the chip,
+and a second process cannot take it.  Its records are labelled
+``platform: cpu`` — they are XLA:CPU's memory analysis of eight virtual
+CPU devices, not a TPU measurement.
+
 Emits ``BENCH_memory.json`` (uploaded as a CI artifact next to the
 other BENCH tables).  ``--smoke`` shrinks both workloads so the whole
 subprocess compiles in seconds.
@@ -90,6 +96,7 @@ def run(smoke: bool = False):
     repo = Path(__file__).resolve().parent.parent
     env = dict(os.environ)
     env["PYTHONPATH"] = str(repo / "src")
+    env["JAX_PLATFORMS"] = "cpu"
     proc = subprocess.run(
         [sys.executable, "-c", _SCRIPT.format(smoke=smoke)], env=env,
         capture_output=True, text=True, timeout=900)
@@ -108,6 +115,7 @@ def run(smoke: bool = False):
             "device_output_bytes": d["output"],
             "device_peak_bytes": d["args"] + d["temp"] + d["output"],
             "host_build_peak_bytes": d["host_peak"],
+            "platform": "cpu",
             "devices": 8,
             "smoke": smoke,
         })

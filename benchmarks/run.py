@@ -22,6 +22,8 @@ def main() -> None:
     ap.add_argument("--smoke", action="store_true")
     args = ap.parse_args()
     wanted = set(args.only.split(","))
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
 
     print("name,us_per_call,derived")
     failures = []

@@ -68,6 +68,7 @@ from repro.core.problem import solve
 from repro.imaging import psf as psf_op
 from repro.imaging.condat import SolverConfig
 from repro.imaging.deconvolve import DeconvolutionProblem
+from repro.launch.cache import enable_compile_cache
 from repro.launch.mesh import smallest_mesh
 
 
@@ -114,4 +115,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

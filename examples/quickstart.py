@@ -14,6 +14,7 @@ import jax.numpy as jnp
 
 from repro.core.bundle import Bundle
 from repro.core.problem import Problem, solve
+from repro.launch.cache import enable_compile_cache
 from repro.launch.mesh import smallest_mesh
 
 
@@ -72,4 +73,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -16,6 +16,7 @@ import numpy as np
 from repro.core.problem import solve
 from repro.data.synthetic import coupled_patches
 from repro.imaging.scdl import SCDLConfig
+from repro.launch.cache import enable_compile_cache
 from repro.launch.mesh import smallest_mesh
 
 
@@ -77,4 +78,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -55,6 +55,7 @@ import jax
 import numpy as np
 
 from repro.imaging import psf as psf_op
+from repro.launch.cache import enable_compile_cache
 from repro.serve import ServeConfig
 from repro.serve.client import ServeClient
 from repro.serve.server import serve_http
@@ -148,5 +149,6 @@ def restart_and_replay():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()
     restart_and_replay()

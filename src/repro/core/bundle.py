@@ -31,10 +31,9 @@ from typing import Any, Callable, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
-
-from repro.core.compat import shard_map
 
 
 def _dp_axes(mesh: Optional[Mesh], axes: Optional[Tuple[str, ...]] = None

@@ -26,10 +26,10 @@ from typing import Any, Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core.bundle import Bundle
-from repro.core.compat import shard_map
 
 
 def make_step(fn: Callable, bundle: Bundle, *, donate: bool = True,

@@ -36,11 +36,31 @@ from repro.core.bundle import Bundle, gather
 from repro.core.problem import Problem, register
 
 
+# The range finder's products run at full fp32 precision: on TPU an f32
+# dot defaults to a single bf16 pass, and the (r, r) Gram squares the
+# conditioning of what that rounds (measured on the chip, PERF.md).
+def _mm(a, b):
+    return jnp.matmul(a, b, precision=jax.lax.Precision.HIGHEST)
+
+
 def svt(mat: jax.Array, thresh) -> jax.Array:
     """Exact singular-value thresholding (sequential reference)."""
     u, s, vt = jnp.linalg.svd(mat, full_matrices=False)
     s = jnp.maximum(s - thresh, 0.0)
     return (u * s[None, :]) @ vt
+
+
+def _orthonormalize(y, axes, eps):
+    """Orthonormal basis of the row-sharded ``y``'s column space through
+    the psum-reduced Gram's eigendecomposition (rank-deficient safe:
+    null directions are clipped, unlike a regularised Cholesky)."""
+    gram = _mm(y.T, y)                               # (r, r)
+    if axes:
+        gram = jax.lax.psum(gram, axes)
+    evals, evecs = jnp.linalg.eigh(gram)
+    scale = jnp.where(evals > eps * jnp.max(evals),
+                      jax.lax.rsqrt(jnp.maximum(evals, 1e-30)), 0.0)
+    return _mm(y, evecs * scale[None, :])
 
 
 def randomized_svt_local(a_local: jax.Array, omega: jax.Array, thresh,
@@ -49,23 +69,21 @@ def randomized_svt_local(a_local: jax.Array, omega: jax.Array, thresh,
 
     a_local: (n_local, p) rows of A; omega: (p, r) replicated test matrix;
     ``axes``: mesh axes to psum over (None == single partition).
+
+    The basis is orthonormalised twice (CholeskyQR2): the Gram squares
+    the condition number, so one pass leaves directions near the ``eps``
+    cut-off off-orthogonal by up to ``eps_f32 / eps`` — a non-projection
+    that fp32 rounding then steers.  The second pass costs one more
+    (r, r) psum and restores orthogonality to working precision.
     """
-    y = a_local @ omega                              # (n_loc, r)
-    gram = y.T @ y                                   # (r, r)
-    if axes:
-        gram = jax.lax.psum(gram, axes)
-    # orthogonalise through the Gram eigendecomposition (rank-deficient
-    # safe: null directions are clipped, unlike a regularised Cholesky)
-    evals, evecs = jnp.linalg.eigh(gram)
-    scale = jnp.where(evals > eps * jnp.max(evals),
-                      jax.lax.rsqrt(jnp.maximum(evals, 1e-30)), 0.0)
-    q = y @ (evecs * scale[None, :])                 # (n_loc, r) orthonormal
-    b = q.T @ a_local                                # (r, p)
+    y = _mm(a_local, omega)                          # (n_loc, r)
+    q = _orthonormalize(_orthonormalize(y, axes, eps), axes, eps)
+    b = _mm(q.T, a_local)                            # (r, p)
     if axes:
         b = jax.lax.psum(b, axes)
     u, s, vt = jnp.linalg.svd(b, full_matrices=False)
     s = jnp.maximum(s - thresh, 0.0)
-    return (q @ u) * s[None, :] @ vt                 # (n_loc, p)
+    return _mm(_mm(q, u) * s[None, :], vt)           # (n_loc, p)
 
 
 def make_test_matrix(p: int, rank: int, oversample: int = 8,
@@ -95,17 +113,18 @@ def _masked_residual(d):
     return d["M"] * (d["X"] - d["Y"])
 
 
-def nuclear_norm_rf(X_loc, omega, axes):
+def nuclear_norm_rf(X_loc, omega, axes, eps: float = 1e-6):
     """Range-finder nuclear norm of a row-sharded matrix (psum-reduced
     (r, r) Gram of the projection, replicated eigen-sqrt-sum) — exact
     when rank(X) <= r, e.g. for every post-SVT iterate.  Shared by the
     lowrank-mode deconvolution objective and the completion workload."""
-    y = X_loc @ omega
-    gram = y.T @ y
+    y = _mm(X_loc, omega)
+    gram = _mm(y.T, y)
     if axes:
         gram = jax.lax.psum(gram, axes)
     s2 = jnp.linalg.eigvalsh(gram)
-    return jnp.sum(jnp.sqrt(jnp.maximum(s2, 0.0)))
+    s2 = jnp.where(s2 > eps * jnp.max(s2), s2, 0.0)
+    return jnp.sum(jnp.sqrt(s2))
 
 
 @register("lowrank")

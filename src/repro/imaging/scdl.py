@@ -58,6 +58,14 @@ from repro.kernels.admm_elwise.ops import admm_elwise
 from repro.kernels.dict_outer.ops import dict_outer_pair
 
 
+# Every product here runs at full fp32 precision: on TPU an f32 dot
+# defaults to a single bf16 pass, and both the ridge solves and the NRMSE
+# residual S - W X^T (a small difference of large terms) amplify that
+# rounding (measured on the chip, PERF.md).
+def _mm(a, b):
+    return jnp.matmul(a, b, precision=jax.lax.Precision.HIGHEST)
+
+
 @dataclass(frozen=True)
 class SCDLConfig:
     n_atoms: int = 512             # A
@@ -112,15 +120,15 @@ def _solve_factor(X, c):
     P, A = X.shape
     eye = lambda n: jnp.eye(n, dtype=X.dtype)
     if P < A:
-        B = 0.5 * c * eye(P) + X @ X.T
+        B = 0.5 * c * eye(P) + _mm(X, X.T)
         C = jsl.cho_solve((jnp.linalg.cholesky(B), True), X)
         if 2 * P < A:
             return {"C": C}
-        Gi = (eye(A) - X.T @ C) / c
+        Gi = (eye(A) - _mm(X.T, C)) / c
     else:
-        G = 2.0 * X.T @ X + c * eye(A)
+        G = 2.0 * _mm(X.T, X) + c * eye(A)
         Gi = jsl.cho_solve((jnp.linalg.cholesky(G), True), eye(A))
-    return {"Gi": Gi, "B2": 2.0 * X @ Gi}
+    return {"Gi": Gi, "B2": 2.0 * _mm(X, Gi)}
 
 
 def _ridge_solve(S, Z, X, F, c):
@@ -128,9 +136,9 @@ def _ridge_solve(S, Z, X, F, c):
     broadcast factor ``F`` from :func:`_solve_factor` — pure GEMMs on
     the sample block."""
     if "Gi" in F:
-        return S @ F["B2"] + Z @ F["Gi"]
-    rhs = 2.0 * (S @ X) + Z
-    return (rhs - (rhs @ X.T) @ F["C"]) / c
+        return _mm(S, F["B2"]) + _mm(Z, F["Gi"])
+    rhs = 2.0 * _mm(S, X) + Z
+    return (rhs - _mm(_mm(rhs, X.T), F["C"])) / c
 
 
 def broadcast_factors(Xh, Xl, cfg: SCDLConfig):
@@ -237,8 +245,8 @@ def make_step_fn(cfg: SCDLConfig):
         d, new_dicts = _iterate(d, rep, axes, cfg)
         # augmented-Lagrangian data terms (the paper's Fig. 14 metric is
         # the reconstruction error of the *calculated dictionaries*)
-        res_h = jnp.sum((d["Sh"] - d["Wh"] @ new_dicts["Xh"].T) ** 2)
-        res_l = jnp.sum((d["Sl"] - d["Wl"] @ new_dicts["Xl"].T) ** 2)
+        res_h = jnp.sum((d["Sh"] - _mm(d["Wh"], new_dicts["Xh"].T)) ** 2)
+        res_l = jnp.sum((d["Sl"] - _mm(d["Wl"], new_dicts["Xl"].T)) ** 2)
         parts = {"res_h": res_h, "res_l": res_l}
         if axes:
             parts = jax.tree.map(lambda x: jax.lax.psum(x, axes), parts)
@@ -273,8 +281,8 @@ def make_cost_fn(cfg: SCDLConfig):
     for the chunk's final iteration."""
 
     def cost(d, rep, axes):
-        res_h = jnp.sum((d["Sh"] - d["Wh"] @ rep["Xh"].T) ** 2)
-        res_l = jnp.sum((d["Sl"] - d["Wl"] @ rep["Xl"].T) ** 2)
+        res_h = jnp.sum((d["Sh"] - _mm(d["Wh"], rep["Xh"].T)) ** 2)
+        res_l = jnp.sum((d["Sl"] - _mm(d["Wl"], rep["Xl"].T)) ** 2)
         parts = {"res_h": res_h, "res_l": res_l}
         if axes:
             parts = jax.tree.map(lambda x: jax.lax.psum(x, axes), parts)

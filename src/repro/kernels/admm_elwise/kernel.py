@@ -16,9 +16,13 @@ Grid: (K / block_k,) over the sample axis, embarrassingly parallel
 (dimension_semantics: parallel); every program touches disjoint rows.
 The ADMM constants (c1, c2, c3 and the thresholds t1 = lam_h/c1,
 t2 = lam_l/c2) are static configuration, baked into the kernel body.
-VMEM per program: ~12 x block_k x A x 4 B ~ 6 MB at block_k = 256,
-A = 512.  Sample counts that don't divide ``block_k`` zero-pad up to a
-whole block (pad rows produce pad rows; the caller slices them off).
+``block_k`` is derived from A against the scoped-VMEM budget
+(``kernels.common.vmem_rows``): a (5, A) row of the stack pads to an
+(8, A) tile, and under ``vmap`` (``solve_many``) Mosaic keeps about six
+such rows live per sample, so A = 512 takes 128 samples (256 overflowed
+16 MiB by 7 MiB).  Sample counts that don't divide ``block_k`` zero-pad
+up to a whole block (pad rows produce pad rows; the caller slices them
+off).
 """
 from __future__ import annotations
 
@@ -28,7 +32,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.common import auto_interpret, pad_leading
+from repro.kernels.common import auto_interpret, pad_leading, vmem_rows
+
+_LIVE_BLOCKS = 6
 
 
 def _admm_kernel(wh_ref, wl_ref, yz_ref, out_ref, *, c1, c2, c3, t1, t2):
@@ -49,11 +55,13 @@ def _admm_kernel(wh_ref, wl_ref, yz_ref, out_ref, *, c1, c2, c3, t1, t2):
 
 
 def admm_elwise_fwd(Wh, Wl, YZ, *, c1, c2, c3, t1, t2,
-                    block_k: int = 256, interpret=None):
+                    block_k=None, interpret=None):
     """Wh/Wl: (K, A); YZ: (K, 5, A).  Returns the updated (K, 5, A)."""
     if interpret is None:
         interpret = auto_interpret()
     K, A = Wh.shape
+    if block_k is None:
+        block_k = vmem_rows((5, A), _LIVE_BLOCKS, cap=256)
     block_k = min(block_k, K)
     ins, k_full = pad_leading([Wh, Wl, YZ], block_k)
     pad = k_full - K
@@ -71,5 +79,6 @@ def admm_elwise_fwd(Wh, Wl, YZ, *, c1, c2, c3, t1, t2,
         out_specs=pl.BlockSpec((block_k, 5, A), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((k_full, 5, A), YZ.dtype),
         interpret=interpret,
+        name="admm_elwise",
     )(*ins)
     return out[:K] if pad else out

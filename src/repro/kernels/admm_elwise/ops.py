@@ -26,7 +26,7 @@ FAMILY = "admm_elwise"
 
 @partial(jax.jit, static_argnames=("c1", "c2", "c3", "t1", "t2",
                                    "block_k", "interpret"))
-def _admm_kernel(Wh, Wl, YZ, *, c1, c2, c3, t1, t2, block_k: int,
+def _admm_kernel(Wh, Wl, YZ, *, c1, c2, c3, t1, t2, block_k,
                  interpret: bool):
     return admm_elwise_fwd(Wh, Wl, YZ, c1=c1, c2=c2, c3=c3,
                            t1=t1, t2=t2, block_k=block_k,
@@ -40,7 +40,7 @@ def _admm_ref(Wh, Wl, YZ, *, c1, c2, c3, t1, t2):
 
 
 def admm_elwise(Wh, Wl, YZ, *, c1, c2, c3, t1, t2,
-                use_kernel=None, block_k: int = 256, interpret=None):
+                use_kernel=None, block_k=None, interpret=None):
     if use_kernel is None:
         use_kernel = not auto_interpret()
     if not use_kernel:

@@ -3,13 +3,21 @@
 Besides the backend probe (:func:`auto_interpret`) and padding helper,
 this module owns **graceful kernel degradation** (DESIGN.md §18): every
 kernel family's public wrapper routes its implementation choice through
-:func:`degraded_call`, so a Pallas construction/lowering failure (or an
-injected ``kernel`` chaos fault) drops the family compiled → interpret
-→ ref *once per process*, with a recorded warning, instead of killing a
-survey-scale run over one miscompiling kernel.
+:func:`degraded_call`, so on a CPU/GPU host a Pallas construction
+failure (or, on any backend, an injected ``kernel`` chaos fault) drops
+the family compiled → interpret → ref *once per process*, with a
+recorded warning.  On a TPU backend a real kernel failure raises
+instead: Mosaic refusing a kernel there is a bug, and running the
+Pallas interpreter on the chip would only hide it.
+
+:func:`reference_kernels` routes every family to its pure-jnp oracle
+for a scope — the plain reference that on-chip checks compare the
+kernel path against.
 """
 from __future__ import annotations
 
+import contextlib
+import math
 import os
 import threading
 import warnings
@@ -19,6 +27,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.resilience import chaos as _chaos
+from repro.resilience.errors import InjectedFault
 
 
 def auto_interpret() -> bool:
@@ -36,6 +45,29 @@ def auto_interpret() -> bool:
     if forced.strip().lower() not in ("", "0", "false", "no"):
         return True
     return jax.default_backend() != "tpu"
+
+
+# Mosaic's default scoped-VMEM limit per kernel on TPU v5e.  Derived
+# block sizes spend three quarters of it and leave the rest to Mosaic's
+# own scratch.
+SCOPED_VMEM_BYTES = 16 << 20
+_VMEM_BUDGET = SCOPED_VMEM_BYTES * 3 // 4
+
+
+def vmem_rows(tail, live: int, cap: int = 128) -> int:
+    """Leading-axis block size for a kernel over ``(rows, *tail)`` blocks.
+
+    ``live`` is how many such blocks the kernel body keeps in VMEM at
+    once (its fp32 values and temporaries, as Mosaic allocates them).
+    Each row of a block occupies ``prod(tail[:-2])`` fp32 tiles of the
+    last two axes padded to the TPU's (8, 128) layout — a 41x41 stamp
+    takes a 48x128 tile, 3.2x its own size.  Returns the largest
+    multiple of 8 in ``[8, cap]`` that fits the budget.
+    """
+    *lead, h, w = tail
+    row = math.prod(lead) * (-(-h // 8) * 8) * (-(-w // 128) * 128) * 4
+    rows = _VMEM_BUDGET // (live * row)
+    return max(8, min(cap, rows // 8 * 8))
 
 
 def pad_leading(arrays, block: int):
@@ -64,6 +96,9 @@ _DEGRADED: Dict[str, int] = {}
 _FALLBACK_EVENTS: List[dict] = []
 _LOCK = threading.Lock()
 
+# set inside ``reference_kernels()``: every family runs its oracle
+_REFERENCE = False
+
 _LEVEL_NAMES = ("compiled", "interpret", "ref")
 
 
@@ -79,6 +114,20 @@ def reset_degradation() -> None:
     with _LOCK:
         _DEGRADED.clear()
         _FALLBACK_EVENTS.clear()
+
+
+@contextlib.contextmanager
+def reference_kernels():
+    """Run every kernel family's pure-jnp reference inside the scope
+    (process-wide, so solves on a serving thread follow it too), as
+    ``use_kernel=False`` would.  The choice is made at trace time: jit
+    the reference computation inside the scope."""
+    global _REFERENCE
+    prev, _REFERENCE = _REFERENCE, True
+    try:
+        yield
+    finally:
+        _REFERENCE = prev
 
 
 def _degrade(family: str, level: int, exc: BaseException) -> None:
@@ -113,15 +162,23 @@ def degraded_call(family: str, *, kernel: Callable[[bool], Any],
 
     ``requested_interpret=None`` defers to :func:`auto_interpret`;
     explicit True counts as starting at the interpret level.
+
+    On a TPU backend only injected faults degrade; any other failure
+    propagates, so a kernel Mosaic refuses fails the run loudly.
     """
+    if _REFERENCE:
+        return ref()
     interpret = (auto_interpret() if requested_interpret is None
                  else requested_interpret)
+    strict = jax.default_backend() == "tpu"
     level = _DEGRADED.get(family, 0)
     if level == 0 and not interpret:
         try:
             _chaos.maybe_raise("kernel", tag=family)
             return kernel(False)
         except Exception as e:  # degrade the family, not the run
+            if strict and not isinstance(e, InjectedFault):
+                raise
             _degrade(family, 1, e)
             level = 1
     if level <= 1:
@@ -129,5 +186,7 @@ def degraded_call(family: str, *, kernel: Callable[[bool], Any],
             _chaos.maybe_raise("kernel", tag=family)
             return kernel(True)
         except Exception as e:  # last resort: the jnp reference
+            if strict and not isinstance(e, InjectedFault):
+                raise
             _degrade(family, 2, e)
     return ref()

@@ -20,7 +20,11 @@ into the kernel body like ``admm_elwise``'s static ADMM constants.
 Grids are 1-D over the flattened leading (record/scale) axis,
 embarrassingly parallel; non-dividing leading sizes zero-pad up to a
 whole block (pad rows produce pad rows; the caller slices them off).
-VMEM per program at block 128, S = 41: ~5 x 128 x 41 x 41 x 4 B ~ 4 MB.
+Block sizes default to what the scoped-VMEM budget allows for the
+stamp size (``kernels.common.vmem_rows``): a 41x41 stamp pads to a
+48x128 tile, and inside a solver's chunk program Mosaic keeps about ten
+fp32 blocks live for either pass (operands, temporaries, outputs), so
+both take 48 rows.
 """
 from __future__ import annotations
 
@@ -29,11 +33,14 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import auto_interpret, pad_leading
+from repro.kernels.common import auto_interpret, pad_leading, vmem_rows
+
+# fp32 blocks each kernel body keeps live in VMEM (see vmem_rows)
+_LIVE_BLOCKS = 10
 
 
 def _primal_kernel(tau_ref, x_ref, ua_ref, g_ref, xn_ref):
-    t = tau_ref[0]
+    t = tau_ref[0, 0]
     x = x_ref[...].astype(jnp.float32)
     xn = jnp.maximum(x - t * g_ref[...].astype(jnp.float32)
                      - t * ua_ref[...].astype(jnp.float32), 0.0)
@@ -41,7 +48,7 @@ def _primal_kernel(tau_ref, x_ref, ua_ref, g_ref, xn_ref):
 
 
 def _primal_xbar_kernel(tau_ref, x_ref, ua_ref, g_ref, xn_ref, xb_ref):
-    t = tau_ref[0]
+    t = tau_ref[0, 0]
     x = x_ref[...].astype(jnp.float32)
     xn = jnp.maximum(x - t * g_ref[...].astype(jnp.float32)
                      - t * ua_ref[...].astype(jnp.float32), 0.0)
@@ -50,7 +57,7 @@ def _primal_xbar_kernel(tau_ref, x_ref, ua_ref, g_ref, xn_ref, xb_ref):
 
 
 def _dual_kernel(sig_ref, u_ref, cn_ref, co_ref, w_ref, out_ref):
-    s = sig_ref[0]
+    s = sig_ref[0, 0]
     v = u_ref[...].astype(jnp.float32) + \
         s * (2.0 * cn_ref[...].astype(jnp.float32)
              - co_ref[...].astype(jnp.float32))
@@ -63,14 +70,16 @@ def _scalar_spec():
 
 
 def condat_primal_fwd(X, U_adj, grad, tau, *, with_xbar: bool = False,
-                      block_n: int = 128, interpret=None):
+                      block_n=None, interpret=None):
     """X/U_adj/grad: (N, S, S); tau scalar.  Returns X_new (and X_bar)."""
     if interpret is None:
         interpret = auto_interpret()
     n, s = X.shape[0], X.shape[-1]
+    if block_n is None:
+        block_n = vmem_rows((s, s), _LIVE_BLOCKS)
     block_n = min(block_n, n)
     ins, n_full = pad_leading([X, U_adj, grad], block_n)
-    tau = jnp.asarray(tau, jnp.float32).reshape((1,))
+    tau = jnp.asarray(tau, jnp.float32).reshape((1, 1))
 
     blk = pl.BlockSpec((block_n, s, s), lambda i: (i, 0, 0))
     shape = jax.ShapeDtypeStruct((n_full, s, s), X.dtype)
@@ -82,21 +91,24 @@ def condat_primal_fwd(X, U_adj, grad, tau, *, with_xbar: bool = False,
         out_specs=[blk, blk] if with_xbar else blk,
         out_shape=[shape, shape] if with_xbar else shape,
         interpret=interpret,
+        name="condat_elwise_primal",
     )(tau, *ins)
     if with_xbar:
         return out[0][:n], out[1][:n]
     return out[:n]
 
 
-def condat_dual_fwd(U, C_new, C_old, W, sig, *, block_m: int = 128,
+def condat_dual_fwd(U, C_new, C_old, W, sig, *, block_m=None,
                     interpret=None):
     """U/C_new/C_old: (M, S, S); W: (M, 1, 1); sig scalar."""
     if interpret is None:
         interpret = auto_interpret()
     m, s = U.shape[0], U.shape[-1]
+    if block_m is None:
+        block_m = vmem_rows((s, s), _LIVE_BLOCKS)
     block_m = min(block_m, m)
     ins, m_full = pad_leading([U, C_new, C_old, W], block_m)
-    sig = jnp.asarray(sig, jnp.float32).reshape((1,))
+    sig = jnp.asarray(sig, jnp.float32).reshape((1, 1))
 
     blk = pl.BlockSpec((block_m, s, s), lambda i: (i, 0, 0))
     out = pl.pallas_call(
@@ -107,5 +119,6 @@ def condat_dual_fwd(U, C_new, C_old, W, sig, *, block_m: int = 128,
         out_specs=blk,
         out_shape=jax.ShapeDtypeStruct((m_full, s, s), U.dtype),
         interpret=interpret,
+        name="condat_elwise_dual",
     )(sig, *ins)
     return out[:m]

@@ -36,7 +36,7 @@ FAMILY = "condat_elwise"
 
 @partial(jax.jit, static_argnames=("with_xbar", "block_n", "interpret"))
 def _primal_kernel(X, U_adj, grad, tau, *, with_xbar: bool,
-                   block_n: int, interpret: bool):
+                   block_n, interpret: bool):
     lead = X.shape[:-2]
     flat = (-1,) + X.shape[-2:]
     out = condat_primal_fwd(X.reshape(flat), U_adj.reshape(flat),
@@ -54,7 +54,7 @@ def _primal_ref(X, U_adj, grad, tau, *, with_xbar: bool):
 
 
 def condat_primal(X, U_adj, grad, tau, *, with_xbar: bool = False,
-                  use_kernel=None, block_n: int = 128, interpret=None):
+                  use_kernel=None, block_n=None, interpret=None):
     if use_kernel is None:
         use_kernel = not auto_interpret()
     if not use_kernel:
@@ -69,8 +69,7 @@ def condat_primal(X, U_adj, grad, tau, *, with_xbar: bool = False,
 
 
 @partial(jax.jit, static_argnames=("block_m", "interpret"))
-def _dual_kernel(U, C_new, C_old, W, sig, *, block_m: int,
-                 interpret: bool):
+def _dual_kernel(U, C_new, C_old, W, sig, *, block_m, interpret: bool):
     lead = U.shape[:-2]
     flat = (-1,) + U.shape[-2:]
     w = jnp.broadcast_to(W, lead + (1, 1)).reshape((-1, 1, 1))
@@ -84,7 +83,7 @@ _dual_ref = jax.jit(condat_dual_ref)
 
 
 def condat_dual(U, C_new, C_old, W, sig, *, use_kernel=None,
-                block_m: int = 128, interpret=None):
+                block_m=None, interpret=None):
     if use_kernel is None:
         use_kernel = not auto_interpret()
     if not use_kernel:

@@ -71,6 +71,7 @@ def dict_outer_fwd(S, W, *, block_k: int = 512, interpret=None):
             jax.ShapeDtypeStruct((A, A), jnp.float32),
         ],
         interpret=interpret,
+        name="dict_outer",
     )(S, W)
 
 
@@ -130,4 +131,5 @@ def dict_outer_pair_fwd(Sh, Sl, Wh, Wl, *, block_k: int = 512,
             jax.ShapeDtypeStruct((A, A), jnp.float32),
         ],
         interpret=interpret,
+        name="dict_outer_pair",
     )(Sh, Sl, Wh, Wl)

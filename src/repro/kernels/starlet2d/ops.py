@@ -26,7 +26,7 @@ FAMILY = "starlet2d"
 
 
 @partial(jax.jit, static_argnames=("scale", "block_n", "interpret"))
-def _smooth_kernel(imgs, *, scale: int, block_n: int, interpret: bool):
+def _smooth_kernel(imgs, *, scale: int, block_n, interpret: bool):
     return smooth_fwd(imgs, scale, block_n=block_n, interpret=interpret)
 
 
@@ -36,7 +36,7 @@ def _smooth_ref(imgs, *, scale: int):
 
 
 def smooth(imgs, *, scale: int, use_kernel: bool = True,
-           block_n: int = 128, interpret=None):
+           block_n=None, interpret=None):
     if not use_kernel:
         return _smooth_ref(imgs, scale=scale)
     return degraded_call(

@@ -19,18 +19,17 @@ from typing import Optional, Tuple
 
 import jax
 
-from repro.core.compat import make_mesh as _make_mesh
-
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
     """Arbitrary mesh for tests/examples (e.g. (2,2) on 4 host devices)."""
-    return _make_mesh(shape, axes)
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def smallest_mesh() -> Optional[object]:

@@ -23,10 +23,9 @@ from typing import Any, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.lax import axis_size
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
-
-from repro.core.compat import axis_size
 
 
 def hierarchical_psum_local(x, *, pod_axis: str = "pod",

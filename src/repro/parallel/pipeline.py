@@ -23,10 +23,10 @@ from typing import Any, Callable, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
+from jax.lax import axis_size
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
-
-from repro.core.compat import axis_size, shard_map
 
 
 def pipeline_apply(layer_fn: Callable, stage_params, x_micro, *,

@@ -212,8 +212,15 @@ def test_open_bucket_discard_shrinks_capacity():
 
 def test_padded_solve_matches_unpadded_bitforbit():
     """A padded instance's valid region reproduces its unpadded single
-    solve bit-for-bit: zero records are trajectory-inert and the
-    replicated derived state is built pre-padding."""
+    solve: zero records are trajectory-inert and the replicated derived
+    state is built pre-padding.
+
+    "Bit-for-bit" holds for the math, not for the compiled programs: the
+    batched program (stacked lanes, freeze mask) is a different XLA
+    program from the single solve, and XLA:CPU groups its fused
+    elementwise arithmetic per program.  Even a lone unpadded lane
+    differs from the single solve by 1 ulp of the stamp's peak after two
+    iterations.  The bound is 4 ulp of the peak (measured: 2)."""
     from repro.core.problem import solve, solve_many
     from repro.imaging import psf as psf_op
     from repro.imaging.condat import SolverConfig
@@ -228,5 +235,6 @@ def test_padded_solve_matches_unpadded_bitforbit():
     for inst, sol in zip(insts, sols):
         ref = solve("deconvolve", *inst, cfg=cfg, chunk=3)
         assert sol.x.shape == ref.x.shape
-        np.testing.assert_array_equal(np.asarray(sol.x),
-                                      np.asarray(ref.x))
+        ulp = np.spacing(np.abs(np.asarray(ref.x)).max())
+        np.testing.assert_allclose(np.asarray(sol.x), np.asarray(ref.x),
+                                   rtol=0, atol=4 * ulp)

@@ -108,7 +108,7 @@ def test_hierarchical_psum_and_compression():
     run_sub("""
     from functools import partial
     from jax.sharding import PartitionSpec as P
-    from repro.core.compat import shard_map
+    from jax import shard_map
     from repro.parallel.collectives import (CompressedReducer,
                                             hierarchical_psum_local)
     mesh = make_mesh((2, 4), ("pod", "data"))

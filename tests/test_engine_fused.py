@@ -119,13 +119,22 @@ def test_scdl_fused_matches_per_step(chunk):
 @pytest.mark.parametrize("chunk", [1, 4])
 def test_scdl_cost_every_matches_on_grid(chunk):
     """SCDL's cost_every (the light step feeds the dictionary broadcast
-    every iteration — ``light_updates_replicated``): identical iterates,
-    objective only on the grid, on both the fused and per-step paths."""
+    every iteration — ``light_updates_replicated``): the same iterates,
+    objective only on the grid, on both the fused and per-step paths.
+
+    The fused chunk with ``cost_every=3`` is a different XLA program
+    (a ``cond`` between full and light steps inside the scan), which
+    XLA:CPU fuses and rounds differently.  This trajectory amplifies
+    rounding about 10^3-fold — a 1-ulp perturbation of S_h moves Xh by
+    up to 4e-5 — so the fused iterates agree to that scale (measured
+    8e-6); the per-step path runs the same step programs either way and
+    stays within fp32 epsilon."""
     S_h, S_l = coupled_patches(256, 25, 9, 16, seed=5)
     cfg = SCDLConfig(n_atoms=16, max_iter=N_ITER)
     Xh1, _, log_1 = train(S_h, S_l, cfg, chunk=chunk, cost_every=1)
     Xh3, _, log_3 = train(S_h, S_l, cfg, chunk=chunk, cost_every=3)
-    np.testing.assert_allclose(Xh3, Xh1, rtol=1e-5, atol=1e-7)
+    np.testing.assert_allclose(Xh3, Xh1, rtol=1e-5,
+                               atol=1e-7 if chunk == 1 else 4e-5)
     c1, c3 = np.asarray(log_1.costs), np.asarray(log_3.costs)
     np.testing.assert_allclose(c3[::3], c1[::3], rtol=1e-5)
     # off-grid entries carry the last evaluated objective forward,

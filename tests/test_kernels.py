@@ -55,6 +55,38 @@ def test_starlet_smooth_non_block_aligned(scale, shape):
                                rtol=1e-5, atol=1e-5)
 
 
+@pytest.mark.parametrize("scale", [3, 4])
+def test_starlet_smooth_whole_period_holes(scale):
+    """Holes as wide as the stamp (8 and 16 on a 16-wide stamp) shift
+    taps by a whole period: the kernel uses them unrolled, as the
+    centre tap, since rolling by 0 is a zero-width slice on TPU."""
+    imgs = jax.random.normal(jax.random.fold_in(KEY, 14), (24, 16, 16))
+    out = k_smooth(imgs, scale=scale)
+    np.testing.assert_allclose(np.asarray(out),
+                               np.asarray(smooth_ref(imgs, scale)),
+                               rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("tail", [(16, 16), (41, 41), (64, 64),
+                                  (130, 130), (4, 41, 41)])
+def test_vmem_rows_fits_the_scoped_budget(tail):
+    from repro.kernels.common import SCOPED_VMEM_BYTES, vmem_rows
+    rows = vmem_rows(tail, live=10)
+    assert rows % 8 == 0 and 8 <= rows <= 128
+    *lead, h, w = tail
+    tile = int(np.prod(lead)) * (-(-h // 8) * 8) * (-(-w // 128) * 128) * 4
+    if rows > 8:                                  # 8 is the floor
+        assert rows * 10 * tile <= SCOPED_VMEM_BYTES * 3 // 4
+        assert (rows + 8) * 10 * tile > SCOPED_VMEM_BYTES * 3 // 4 or \
+            rows == 128
+
+
+def test_vmem_rows_at_the_stamp_size():
+    from repro.kernels.common import vmem_rows
+    # a 41x41 stamp pads to a 48x128 fp32 tile (24 KiB)
+    assert vmem_rows((41, 41), live=10) == 48
+
+
 def test_starlet_batched_forward_adjoint_match_reference():
     """ops.forward/adjoint (the condat hot path) vs per-stamp vmap of the
     imaging reference, on a non-block-aligned batch."""

@@ -342,6 +342,58 @@ def test_kernel_degradation_reset(fresh_kernels):
         condat_dual(U, C, 0.9 * C, W, 0.5, use_kernel=True)
 
 
+def _broken_kernel(interpret):
+    raise RuntimeError("Mosaic refused the kernel")
+
+
+def test_kernel_failure_degrades_on_cpu_backend(fresh_kernels):
+    with pytest.warns(RuntimeWarning, match="degraded"):
+        out = kcommon.degraded_call("starlet2d", kernel=_broken_kernel,
+                                    ref=lambda: "ref",
+                                    requested_interpret=False)
+    assert out == "ref"
+    assert [e["to"] for e in kcommon.kernel_fallbacks()] == \
+        ["interpret", "ref"]
+
+
+def test_kernel_failure_raises_on_tpu_backend(fresh_kernels, monkeypatch):
+    """On the chip a kernel Mosaic refuses is a bug to fix: no silent
+    fallback to the interpreter, at either level, and no event.  An
+    injected chaos fault still degrades, so the drills keep working."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    for interpret in (False, True):
+        with pytest.raises(RuntimeError, match="Mosaic refused"):
+            kcommon.degraded_call("starlet2d", kernel=_broken_kernel,
+                                  ref=lambda: "ref",
+                                  requested_interpret=interpret)
+    assert kcommon.kernel_fallbacks() == ()
+    cc = chaos.ChaosConfig.parse("kernel:starlet2d@0")
+    with chaos.active_chaos(cc):
+        with pytest.warns(RuntimeWarning, match="degraded"):
+            out = kcommon.degraded_call(
+                "starlet2d", kernel=lambda interp: ("kernel", interp),
+                ref=lambda: "ref", requested_interpret=False)
+    assert out == ("kernel", True)
+    assert [e["to"] for e in kcommon.kernel_fallbacks()] == ["interpret"]
+
+
+def test_reference_kernels_scope(fresh_kernels):
+    calls = []
+
+    def kernel(interp):
+        calls.append(interp)
+        return "kernel"
+
+    with kcommon.reference_kernels():
+        assert kcommon.degraded_call("dict_outer", kernel=kernel,
+                                     ref=lambda: "ref") == "ref"
+    assert calls == []
+    assert kcommon.degraded_call("dict_outer", kernel=kernel,
+                                 ref=lambda: "ref",
+                                 requested_interpret=True) == "kernel"
+    assert kcommon.kernel_fallbacks() == ()
+
+
 def test_solve_reports_kernel_fallbacks(fresh_kernels, psf_data,
                                         ref_trajs):
     # the deconvolution step traces the starlet kernels: an injected

@@ -39,13 +39,13 @@ def _deconv_cfg(**kw):
     return SolverConfig(**base)
 
 
-def _assert_instance_parity(sol, ref, rtol=1e-4):
+def _assert_instance_parity(sol, ref, rtol=1e-4, atol=1e-6):
     fin = np.isfinite(np.asarray(ref.log.costs))
     np.testing.assert_allclose(np.asarray(sol.log.costs)[fin],
                                np.asarray(ref.log.costs)[fin], rtol=rtol)
     for a, b in zip(jax.tree.leaves(sol.x), jax.tree.leaves(ref.x)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=rtol, atol=1e-6)
+                                   rtol=rtol, atol=atol)
 
 
 # =====================================================================
@@ -76,12 +76,17 @@ def test_lowrank_parity():
         M = (r.random((n, p)) < 0.6).astype(np.float32)
         return jnp.asarray(Y), jnp.asarray(M)
 
+    # The iterate is an SVT output: its singular vectors carry rounding
+    # of order eps * sigma_1 / gap, which the batched program (another
+    # XLA program) rounds differently.  A 1-ulp perturbation of Y moves
+    # the single-solve iterate by up to 2.5e-6 of its peak, so iterates
+    # agree to atol 1e-5; the objective keeps rtol 1e-4.
     insts = [make(8, 10, 0), make(6, 10, 1), make(8, 12, 2)]
     cfg = CompletionConfig(rank=4, max_iter=ITERS, tol=0.0)
     sols = solve_many("lowrank", insts, cfg=cfg, chunk=CHUNK)
     for inst, sol in zip(insts, sols):
         _assert_instance_parity(
-            sol, solve("lowrank", *inst, cfg=cfg, chunk=CHUNK))
+            sol, solve("lowrank", *inst, cfg=cfg, chunk=CHUNK), atol=1e-5)
 
 
 def test_scdl_parity():
