@@ -23,7 +23,11 @@ same solve with every kernel family on its jnp oracle, under
 ``jax.default_matmul_precision("highest")``, on the same chip — with the
 tolerance and the reason for it, ``kernels.common.kernel_fallbacks()``
 (must be empty), and for each kernel family of the phase whether it is a
-``tpu_custom_call`` in the compiled chunk program.
+``tpu_custom_call`` in the compiled chunk program.  Completion (e) runs
+no kernel, so its reference is the known matrix it completes: the error
+is that of the unobserved entries.  The phases built on the randomized
+SVT, (b) and (e), also check one SVT on the chip against the exact SVT
+in float64 on the host.
 
 ``--chips 4`` runs only sparse deconvolution and SCDL over a (4, 1)
 data mesh, checks that every data leaf spans the four devices and that
@@ -32,8 +36,9 @@ the same inputs solved on one device in the same process.
 
 The last line of standard output is the JSON contract line
 ``{"ok": true, "device": {...}}``.  The script exits non-zero, and
-prints no contract line, when any phase fails, when JAX finds no TPU,
-or when ``REPRO_FORCE_INTERPRET`` is set.  It uses the persistent
+prints no contract line, when any phase fails, when JAX finds no TPU
+or another number of TPU devices than ``--chips``, or when
+``REPRO_FORCE_INTERPRET`` is set.  It uses the persistent
 compilation cache (``JAX_COMPILATION_CACHE_DIR`` where set, else
 ``<repo>/.jax_cache``).
 """
@@ -193,10 +198,9 @@ class Smoke:
                 "highest"):
             return fn()
 
-    def report(self, name, *, xs, refs, tol, why, extra, hlo):
+    def report(self, name, *, xs, err, tol, why, extra, hlo):
         from repro.kernels.common import kernel_fallbacks
         checks_ok = extra.pop("ok", True)
-        err = rel_err(xs, refs)
         fams = custom_calls(hlo, FAMILIES_OF[name])
         fallbacks = list(kernel_fallbacks())
         finite = all(np.isfinite(np.asarray(x)).all() for x in xs)
@@ -236,6 +240,7 @@ class Smoke:
         extra = dict(stamps=int(data.Y.shape[0]),
                      stamp=int(data.Y.shape[-1]), n_scales=4, **timing,
                      data_leaf_devices=leaf_devices(sol.bundle))
+        check_operator(extra, xs[0], sol.bundle.data["HX"], data.psfs)
         del sol
         return xs, extra, self.chunk_hlo(hlo)
 
@@ -318,7 +323,9 @@ class Smoke:
 
     def completion(self):
         """A rank-8 matrix the size of the stamp population's pixel
-        matrix (one 41x41 stamp per row), 60% of entries observed."""
+        matrix (one 41x41 stamp per row), 60% of entries observed.
+        Returns the recovered matrix, the relative Frobenius error of
+        its unobserved entries against the known matrix, extra, hlo."""
         from repro.imaging.lowrank import CompletionConfig
         k1, k2, k3 = self.jax.random.split(self._key(20), 3)
         n, p, r = STAMPS, 41 * 41, 8
@@ -326,14 +333,41 @@ class Smoke:
         Y = (self.jax.random.normal(k1, (n, r))
              @ self.jax.random.normal(k2, (r, p))) / np.sqrt(r)
         M = (self.jax.random.uniform(k3, (n, p)) < 0.6).astype(jnp.float32)
-        cfg = CompletionConfig(rank=16, max_iter=ITERS, tol=0.0)
+        # the range finder must overshoot the rank: between SVTs the
+        # masked residual raises the iterate's rank (a 32-column finder
+        # stays at 0.4 recovery error after 24 steps)
+        cfg = CompletionConfig(rank=COMPLETION_RANK, oversample=
+                               COMPLETION_RANK, max_iter=ITERS, tol=0.0)
         sol, xs, timing = self.timed_solve("lowrank", Y, M, cfg=cfg)
         del sol
-        hlo = self.chunk_hlo(True)
-        refs = self.reference(lambda: self.timed_solve(
-            "lowrank", Y, M, cfg=cfg)[1])
-        self.chunk_hlo(False)
-        return xs, refs, dict(n=n, p=p, true_rank=r, **timing), hlo
+        hidden = np.asarray(M) == 0
+        Y = np.asarray(Y)
+        err = float(np.linalg.norm((xs[0] - Y)[hidden])
+                    / np.linalg.norm(Y[hidden]))
+        extra = dict(n=n, p=p, true_rank=r, observed=0.6, **timing)
+        self.svt_check(extra, n, p, COMPLETION_RANK, 2 * COMPLETION_RANK)
+        return xs, err, extra, self.chunk_hlo(True)
+
+    def svt_check(self, extra, n, p, rank, columns):
+        """The randomized SVT on the chip against the exact SVT in
+        float64 on the host, on an (n, p) matrix of the range finder's
+        rank with known singular vectors, thresholded at its median
+        singular value; recorded in ``extra``."""
+        from repro.imaging import lowrank as lr
+        rng = np.random.default_rng(self.seed)
+        U, _ = np.linalg.qr(rng.standard_normal((n, rank)))
+        V, _ = np.linalg.qr(rng.standard_normal((p, rank)))
+        s = np.geomspace(1.0, 0.05, rank) * np.sqrt(n * p)
+        t = float(np.median(s))
+        A = (U * s) @ V.T
+        exact = (U * np.maximum(s - t, 0.0)) @ V.T
+        omega = lr.make_test_matrix(p, rank, columns - rank)
+        got = self.jax.jit(lr.randomized_svt_local)(
+            self.jax.numpy.asarray(A, np.float32), omega, t)
+        err = rel_err([got], [exact])
+        extra.update(svt_f64_err=err, svt_tol=SVT_TOL[0],
+                     svt_tol_reason=SVT_TOL[1])
+        extra["ok"] = extra.get("ok", True) and err <= SVT_TOL[0]
 
 
 # ---------------------------------------------------------------------
@@ -341,12 +375,24 @@ class Smoke:
 # ---------------------------------------------------------------------
 
 DECONV_TOL = (1e-4, "both paths run the same fp32 elementwise math and "
-              "FFTs, only grouped differently; on CPU a 1-ulp change of "
-              "the input moves the result by 3e-7")
+              "DFT products, only grouped differently; on CPU a 1-ulp "
+              "change of the input moves the result by 3e-7")
 LOWRANK_TOL = (1e-3, "the randomized SVT's Gram squares the conditioning "
                "of its products; at full fp32 precision a 1-ulp change of "
                "the input moves the iterate by 2e-6 to 4e-6 on CPU, while "
                "one bf16 pass per dot moves it by 1e-2")
+OPERATOR_TOL = (1e-5, "fp32 DFT products at full precision over 81-point "
+                "grids: 1.5e-7 from float64 on CPU; the chip's FFT "
+                "was 0.25 off at 5000 stamps and more")
+SVT_TOL = (1e-4, "fp32 range finder on a matrix of its own rank with "
+           "singular values spanning 20x: 1.6e-6 to 1.7e-6 from the float64 "
+           "SVT on CPU at these shapes; one bf16 pass per product would "
+           "round at about 4e-3")
+COMPLETION_TOL = (5e-2, "recovery of the unobserved 40% after 24 "
+                  "proximal-gradient steps, set by convergence, not "
+                  "rounding: 6.8e-3 on CPU; zero fill is 1.0, and a "
+                  "32-column range finder stays at 0.4")
+COMPLETION_RANK = 24
 SCDL_TOL = (1e-3, "NRMSE trajectory: on CPU a 1-ulp change of the input "
             "moves it by 3e-5; 1e-3 leaves room for the chip's other fp32 "
             "grouping of the same sums")
@@ -354,6 +400,25 @@ SCDL_DICT_TOL = (5e-2, "the dictionaries are not unique along directions "
                  "the objective does not see (on CPU a 1-ulp change of the "
                  "input moves them by 7e-3 of their largest entry), and "
                  "dict_outer's in-kernel f32 dot takes one bf16 pass")
+
+
+def check_operator(extra, X, HX, psfs) -> None:
+    """The PSF operator as the chunk program computed it — the carried
+    ``HX`` is H applied to the final iterate — against the same
+    convolution in float64 numpy on the host."""
+    from repro.imaging.psf import pad_for
+    X, psfs = np.asarray(X, np.float64), np.asarray(psfs, np.float64)
+    s, h = X.shape[-1], psfs.shape[-1]
+    pad = pad_for(s, h)
+    grid = np.zeros(psfs.shape[:-2] + (pad, pad))
+    grid[..., :h, :h] = psfs
+    kf = np.fft.rfft2(np.roll(grid, (-(h // 2), -(h // 2)), axis=(-2, -1)))
+    ref = np.fft.irfft2(np.fft.rfft2(X, s=(pad, pad)) * kf,
+                        s=(pad, pad))[..., :s, :s]
+    err = rel_err([np.asarray(HX)], [ref])
+    extra.update(operator_f64_err=err, operator_tol=OPERATOR_TOL[0],
+                 operator_tol_reason=OPERATOR_TOL[1])
+    extra["ok"] = extra.get("ok", True) and err <= OPERATOR_TOL[0]
 
 
 def check_dictionaries(extra, dicts, refs) -> None:
@@ -366,31 +431,36 @@ def check_dictionaries(extra, dicts, refs) -> None:
 
 
 def run_one_chip(sm: Smoke) -> None:
+    from repro.imaging.condat import SolverConfig
     data = sm.population(STAMPS)
     for name, mode, (tol, why) in (
             ("sparse_deconvolution", "sparse", DECONV_TOL),
             ("lowrank_deconvolution", "lowrank", LOWRANK_TOL)):
         xs, extra, hlo = sm.deconvolution(data, mode)
         refs = sm.deconvolution_ref(data, mode)
-        sm.report(name, xs=xs, refs=refs, tol=tol, why=why, extra=extra,
-                  hlo=hlo)
+        if mode == "lowrank":
+            rank = SolverConfig().rank
+            sm.svt_check(extra, STAMPS, 41 * 41, rank, rank + 8)
+        sm.report(name, xs=xs, err=rel_err(xs, refs), tol=tol, why=why,
+                  extra=extra, hlo=hlo)
     del data
 
     S_h, S_l = sm.scdl_data()
     xs, dicts, extra, hlo = sm.scdl(S_h, S_l)
     refs, ref_dicts = sm.scdl_ref(S_h, S_l)
     check_dictionaries(extra, dicts, ref_dicts)
-    sm.report("scdl", xs=xs, refs=refs, tol=SCDL_TOL[0], why=SCDL_TOL[1],
-              extra=extra, hlo=hlo)
+    sm.report("scdl", xs=xs, err=rel_err(xs, refs), tol=SCDL_TOL[0],
+              why=SCDL_TOL[1], extra=extra, hlo=hlo)
     del S_h, S_l
 
     xs, refs, extra, hlo = sm.serve()
-    sm.report("serve", xs=xs, refs=refs, tol=DECONV_TOL[0],
+    sm.report("serve", xs=xs, err=rel_err(xs, refs), tol=DECONV_TOL[0],
               why=DECONV_TOL[1], extra=extra, hlo=hlo)
 
-    xs, refs, extra, hlo = sm.completion()
-    sm.report("lowrank_completion", xs=xs, refs=refs, tol=LOWRANK_TOL[0],
-              why=LOWRANK_TOL[1], extra=extra, hlo=hlo)
+    xs, err, extra, hlo = sm.completion()
+    sm.report("lowrank_completion", xs=xs, err=err,
+              tol=COMPLETION_TOL[0], why=COMPLETION_TOL[1], extra=extra,
+              hlo=hlo)
 
 
 def run_four_chips(sm: Smoke) -> None:
@@ -405,7 +475,8 @@ def run_four_chips(sm: Smoke) -> None:
     xs, extra, hlo = sm.deconvolution(data, "sparse", mesh=mesh)
     extra.update(where, ok=extra["data_leaf_devices"] == n_dev)
     refs, _, _ = sm.deconvolution(data, "sparse", hlo=False)
-    sm.report("sparse_deconvolution", xs=xs, refs=refs, tol=DECONV_TOL[0],
+    sm.report("sparse_deconvolution", xs=xs, err=rel_err(xs, refs),
+              tol=DECONV_TOL[0],
               why="mesh vs one device: " + DECONV_TOL[1], extra=extra,
               hlo=hlo)
     del data
@@ -419,7 +490,7 @@ def run_four_chips(sm: Smoke) -> None:
                    and extra["all_reduces"] > 0)
     refs, ref_dicts, _, _ = sm.scdl(S_h, S_l, hlo=False)
     check_dictionaries(extra, dicts, ref_dicts)
-    sm.report("scdl", xs=xs, refs=refs, tol=SCDL_TOL[0],
+    sm.report("scdl", xs=xs, err=rel_err(xs, refs), tol=SCDL_TOL[0],
               why="mesh vs one device (psum order differs): "
                   + SCDL_TOL[1], extra=extra, hlo=hlo)
 
@@ -442,8 +513,9 @@ def main() -> None:
     devices = jax.devices()
     if devices[0].platform != "tpu":
         _fail(f"no TPU: JAX found {devices[0].platform} devices only")
-    if len(devices) < args.chips:
-        _fail(f"--chips {args.chips} but JAX found {len(devices)} devices")
+    if len(devices) != args.chips:
+        _fail(f"--chips {args.chips} but JAX found {len(devices)} TPU "
+              f"devices; expose exactly that many")
 
     sys.path.insert(0, str(ROOT / "src"))
     from repro.launch.cache import enable_compile_cache

@@ -36,11 +36,15 @@ from repro.core.bundle import Bundle, gather
 from repro.core.problem import Problem, register
 
 
-# The range finder's products run at full fp32 precision: on TPU an f32
-# dot defaults to a single bf16 pass, and the (r, r) Gram squares the
-# conditioning of what that rounds (measured on the chip, PERF.md).
-def _mm(a, b):
+def matmul_fp32(a, b):
+    """``a @ b`` at full fp32 precision.  On TPU an f32 dot defaults to
+    a single bf16 pass; the range finder's (r, r) Gram squares the
+    conditioning of what that rounds (measured on the chip, PERF.md),
+    and SCDL and the PSF operator's DFTs use it too."""
     return jnp.matmul(a, b, precision=jax.lax.Precision.HIGHEST)
+
+
+_mm = matmul_fp32
 
 
 def svt(mat: jax.Array, thresh) -> jax.Array:
@@ -109,21 +113,33 @@ class CompletionConfig:
     tol: float = 1e-4
 
 
+# Gram eigenvalues within this many ulps of the largest are rounding
+NOISE_ULPS = 4
+
+
 def _masked_residual(d):
     return d["M"] * (d["X"] - d["Y"])
 
 
-def nuclear_norm_rf(X_loc, omega, axes, eps: float = 1e-6):
-    """Range-finder nuclear norm of a row-sharded matrix (psum-reduced
-    (r, r) Gram of the projection, replicated eigen-sqrt-sum) — exact
-    when rank(X) <= r, e.g. for every post-SVT iterate.  Shared by the
-    lowrank-mode deconvolution objective and the completion workload."""
+def nuclear_norm_rf(X_loc, omega, axes):
+    """Range-finder nuclear norm of a row-sharded matrix: the nuclear
+    norm of its sketch ``X @ omega`` (psum-reduced (r, r) Gram of the
+    projection, replicated eigen-sqrt-sum), which captures every
+    direction of X when rank(X) <= r, e.g. for every post-SVT iterate.
+    Shared by the lowrank-mode deconvolution objective and the
+    completion workload.
+
+    Gram eigenvalues below a few ulps of the largest are rounding, not
+    spectrum (their square roots would add about sqrt(eps) * sigma_1
+    each, and move with any 1-ulp change of the input), so they count
+    as zero: singular values below about 7e-4 * sigma_1 in fp32."""
     y = _mm(X_loc, omega)
     gram = _mm(y.T, y)
     if axes:
         gram = jax.lax.psum(gram, axes)
     s2 = jnp.linalg.eigvalsh(gram)
-    s2 = jnp.where(s2 > eps * jnp.max(s2), s2, 0.0)
+    floor = NOISE_ULPS * jnp.finfo(s2.dtype).eps * jnp.max(s2)
+    s2 = jnp.where(s2 > floor, s2, 0.0)
     return jnp.sum(jnp.sqrt(s2))
 
 

@@ -2,8 +2,11 @@
 
 H(X) = [H^0 x^0, ..., H^n x^n]: every galaxy stamp is convolved with the
 PSF at its own sky position (object-oriented deconvolution, paper §4.1).
-FFT-based valid-centred convolution on padded grids; the adjoint is
-correlation (conjugate in Fourier domain) — property-tested.
+Fourier-domain valid-centred convolution on padded grids; the adjoint
+is correlation (conjugate in Fourier domain) — property-tested.  The
+transforms are DFTs written as products with cos/sin bases
+(:func:`rfft2_pad`, :func:`irfft2_crop`), not ``jnp.fft``: XLA:TPU's FFT
+computed the operator wrong at large stamp counts.
 
 Paired-FFT engine (DESIGN.md §16): the padded grid is the *smallest
 fast FFT size >= 2S - 1* derived per stamp (the seed hardcoded 96 for
@@ -27,6 +30,9 @@ from typing import NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+
+from repro.imaging.lowrank import matmul_fp32 as _mm
 
 STAMP = 41
 
@@ -61,14 +67,61 @@ def _real(x: jax.Array) -> jax.Array:
         jnp.dtype(x.dtype).itemsize >= 4 else x.astype(jnp.float32)
 
 
+# ------------------------------------------------ DFTs as matmuls
+# The padded-grid transforms are DFTs written as dense products with
+# cos/sin bases, at full fp32 precision.  On a TPU v5e, XLA's own FFT
+# put the operator 25% off float64 at 5000 stamps and more (right at
+# 2500 or fewer), so the convolution does not go through it (PERF.md).
+# Only the s x s corner of the padded grid is ever nonzero on input or
+# read on output, so every product has s (not pad) on one side.
+
+def _basis(n_out: int, n_in: int, pad: int, shift: int = 0):
+    """cos and sin of 2 pi k (n - shift) / pad, k < n_out, n < n_in."""
+    k = np.arange(n_out)[:, None]
+    n = np.arange(n_in)[None, :] - shift
+    ang = 2.0 * np.pi * ((k * n) % pad) / pad
+    return (jnp.asarray(np.cos(ang), jnp.float32),
+            jnp.asarray(np.sin(ang), jnp.float32))
+
+
+def rfft2_pad(x: jax.Array, pad: int, shift: int = 0) -> jax.Array:
+    """``jnp.fft.rfft2`` of the (pad, pad) grid that holds ``x`` (...,
+    s, s) at rows and columns ``(n - shift) % pad`` and zeros elsewhere:
+    (..., pad, pad // 2 + 1) complex.  Two products: the last axis
+    against [cos; -sin], then axis -2 against the real form of the
+    complex DFT matrix."""
+    s = x.shape[-1]
+    half = pad // 2 + 1
+    c_h, s_h = _basis(half, s, pad, shift)
+    c_f, s_f = _basis(pad, s, pad, shift)
+    t = _mm(_real(x), jnp.concatenate([c_h, -s_h]).T)   # (..., s, 2 half)
+    t = jnp.concatenate([t[..., :half], t[..., half:]], axis=-2)
+    f = _mm(jnp.block([[c_f, s_f], [-s_f, c_f]]), t)    # (..., 2 pad, half)
+    return jax.lax.complex(f[..., :pad, :], f[..., pad:, :])
+
+
+def irfft2_crop(y: jax.Array, pad: int, s: int) -> jax.Array:
+    """``jnp.fft.irfft2(y, s=(pad, pad))[..., :s, :s]`` for the half
+    spectrum ``y`` (..., pad, pad // 2 + 1)."""
+    half = y.shape[-1]
+    c_f, s_f = _basis(s, pad, pad)
+    z = _mm(jnp.block([[c_f, -s_f], [s_f, c_f]]),       # inverse, axis -2
+            jnp.concatenate([jnp.real(y), jnp.imag(y)], axis=-2))
+    z = jnp.concatenate([z[..., :s, :], z[..., s:, :]], axis=-1)
+    # the half spectrum stands for its conjugate mirror: weight 2, but
+    # 1 for the zero frequency and (even pad) the Nyquist one
+    w = np.full(half, 2.0)
+    w[0] = 1.0
+    if pad % 2 == 0:
+        w[-1] = 1.0
+    c_h, s_h = _basis(s, half, pad)
+    return _mm(z, jnp.concatenate([c_h * w, -s_h * w], axis=1).T) / (
+        pad * pad)
+
+
 def _fft_kernel(psf: jax.Array, pad: int) -> jax.Array:
     """Centered PSF -> rfft2 on the padded grid (kernel rolled to origin)."""
-    psf = _real(psf)
-    h = psf.shape[-2]
-    padded = jnp.zeros(psf.shape[:-2] + (pad, pad), psf.dtype)
-    padded = padded.at[..., :h, :h].set(psf)
-    padded = jnp.roll(padded, (-(h // 2), -(h // 2)), axis=(-2, -1))
-    return jnp.fft.rfft2(padded)
+    return rfft2_pad(psf, pad, shift=psf.shape[-2] // 2)
 
 
 def convolve(x: jax.Array, psf: jax.Array, adjoint: bool = False
@@ -124,11 +177,10 @@ def convolve_f(x: jax.Array, kf: jax.Array, adjoint: bool = False
     """Same as :func:`convolve` with the PSF kernel FFT precomputed."""
     s = x.shape[-1]
     pad = grid_of(kf)
-    xf = jnp.fft.rfft2(_real(x), s=(pad, pad))
+    xf = rfft2_pad(x, pad)
     if adjoint:
         kf = jnp.conj(kf)
-    out = jnp.fft.irfft2(xf * kf, s=(pad, pad))
-    return out[..., :s, :s].astype(x.dtype)
+    return irfft2_crop(xf * kf, pad, s).astype(x.dtype)
 
 
 def H_f(X: jax.Array, kf: jax.Array) -> jax.Array:
@@ -170,8 +222,7 @@ def conv_pair_f(A: jax.Array, B: jax.Array, kf_pair: jax.Array
     s = A.shape[-1]
     pad = grid_of(kf_pair)
     z = jnp.stack([_real(A), _real(B)], axis=-3)     # (n, 2, S, S)
-    zf = jnp.fft.rfft2(z, s=(pad, pad))
-    out = jnp.fft.irfft2(zf * kf_pair, s=(pad, pad))[..., :s, :s]
+    out = irfft2_crop(rfft2_pad(z, pad) * kf_pair, pad, s)
     return out[..., 0, :, :].astype(A.dtype), \
         out[..., 1, :, :].astype(B.dtype)
 

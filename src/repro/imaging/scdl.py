@@ -54,16 +54,14 @@ import jax.scipy.linalg as jsl
 
 from repro.core.bundle import Bundle
 from repro.core.problem import Problem, register, solve
+from repro.imaging.lowrank import matmul_fp32 as _mm
 from repro.kernels.admm_elwise.ops import admm_elwise
 from repro.kernels.dict_outer.ops import dict_outer_pair
 
-
-# Every product here runs at full fp32 precision: on TPU an f32 dot
-# defaults to a single bf16 pass, and both the ridge solves and the NRMSE
-# residual S - W X^T (a small difference of large terms) amplify that
-# rounding (measured on the chip, PERF.md).
-def _mm(a, b):
-    return jnp.matmul(a, b, precision=jax.lax.Precision.HIGHEST)
+# Every product here runs at full fp32 precision (``_mm``): both the
+# ridge solves and the NRMSE residual S - W X^T (a small difference of
+# large terms) amplify a bf16 pass's rounding (measured on the chip,
+# PERF.md).
 
 
 @dataclass(frozen=True)

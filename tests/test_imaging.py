@@ -198,6 +198,22 @@ def test_randomized_svt_matches_exact():
                                rtol=5e-3, atol=5e-3)
 
 
+def test_nuclear_norm_rf_keeps_small_singular_values():
+    """The range-finder nuclear norm (of the sketch X @ omega) counts
+    every singular value above the fp32 rounding floor (~7e-4 of the
+    largest), however small next to it, and none below."""
+    rng = np.random.default_rng(3)
+    U, _ = np.linalg.qr(rng.normal(size=(200, 6)))
+    V, _ = np.linalg.qr(rng.normal(size=(60, 6)))
+    s = np.array([1.0, 0.3, 0.05, 0.01, 3e-3, 1e-6])
+    X = (U * s) @ V.T
+    omega = lr.make_test_matrix(60, rank=8, key=KEY)
+    sk = np.linalg.svd(X @ np.asarray(omega, np.float64), compute_uv=False)
+    assert sk[4] > 3 * 7e-4 * sk[0] and sk[5] < 1e-5 * sk[0]
+    nuc = float(lr.nuclear_norm_rf(jnp.asarray(X, jnp.float32), omega, ()))
+    np.testing.assert_allclose(nuc, sk[:5].sum(), rtol=1e-4)
+
+
 # -------------------------------------------------- Algorithm 2 (SCDL)
 def _seed_scdl_reference(S_h, S_l, cfg, iters):
     """The pre-overhaul SCDL math, verbatim: per-iteration Gram rebuild +
