@@ -1,0 +1,8 @@
+"""Device ms per solver iteration of the traced window's leaf ops whose
+innermost program scope is ``objective``, averaged over the chips
+(see scopes.scope_ms)."""
+from scopes import scope_ms
+
+
+def read(reading):
+    return scope_ms(reading.trace, "objective", reading.iters)

@@ -15,9 +15,8 @@ One chip runs five phases, each through ``repro.core.problem.solve`` /
   (e) the ``lowrank`` completion Problem on a stamp-population-sized
       matrix.
 
-Each phase prints one JSON line: XLA compile seconds and steady seconds
-per iteration (kept apart; the driver ends every chunk in
-``block_until_ready``), the maximum relative error
+Each phase prints one JSON line: the iterations run, the maximum
+relative error
 ``max|x - x_ref| / max|x_ref|`` against a plain float32 reference — the
 same solve with every kernel family on its jnp oracle, under
 ``jax.default_matmul_precision("highest")``, on the same chip — with the
@@ -76,25 +75,8 @@ def _fail(msg: str) -> None:
 
 
 # ---------------------------------------------------------------------
-# Observation: compile time and the compiled chunk programs
+# Observation: the compiled chunk programs
 # ---------------------------------------------------------------------
-
-class CompileClock:
-    """Seconds JAX spends in XLA compilation (persistent-cache loads
-    included), from JAX's own monitoring events."""
-
-    def __init__(self, jax):
-        self.seconds = 0.0
-        jax.monitoring.register_event_duration_secs_listener(self._on)
-
-    def _on(self, event, duration, **_):
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.seconds += duration
-
-    def take(self) -> float:
-        s, self.seconds = self.seconds, 0.0
-        return s
-
 
 class ChunkPrograms:
     """Records the chunk programs the drivers build (the jitted engine
@@ -166,13 +148,6 @@ def leaf_devices(bundle) -> int:
                for leaf in jax.tree.leaves(bundle.data))
 
 
-def steady_per_iter(log, chunk: int) -> float:
-    """Mean seconds per iteration over the chunks after the first (the
-    first dispatch of a chunk length includes its compilation)."""
-    t = np.asarray(log.times[chunk:])
-    return float(t.mean()) if t.size else float("nan")
-
-
 # ---------------------------------------------------------------------
 # Workloads
 # ---------------------------------------------------------------------
@@ -182,7 +157,6 @@ class Smoke:
         self.jax = jax
         self.seed = seed
         from repro.core import driver
-        self.clock = CompileClock(jax)
         self.programs = ChunkPrograms(jax, driver)
         self.ok = True
 
@@ -213,18 +187,11 @@ class Smoke:
         print(json.dumps(line), flush=True)
         self.ok &= ok
 
-    def timed_solve(self, *args, chunk=None, **kwargs):
+    def solve(self, *args, chunk=None, **kwargs):
         from repro.core.problem import solve
-        chunk = chunk or CHUNK
-        self.clock.take()
-        t0 = time.perf_counter()
-        sol = solve(*args, chunk=chunk, **kwargs)
+        sol = solve(*args, chunk=chunk or CHUNK, **kwargs)
         xs = [np.asarray(x) for x in self.jax.tree.leaves(sol.x)]
-        wall = time.perf_counter() - t0
-        timing = {"compile_s": self.clock.take(), "wall_s": wall,
-                  "steady_s_per_iter": steady_per_iter(sol.log, chunk),
-                  "iters": len(sol.log.costs)}
-        return sol, xs, timing
+        return sol, xs, {"iters": len(sol.log.costs)}
 
     # ------------------------------------------------------- phases
     def population(self, n):
@@ -235,10 +202,10 @@ class Smoke:
         """Returns (result, extra, compiled chunk HLO if ``hlo``)."""
         from repro.imaging.condat import SolverConfig
         cfg = SolverConfig(mode=mode, n_scales=4, max_iter=ITERS, tol=0.0)
-        sol, xs, timing = self.timed_solve(
+        sol, xs, ran = self.solve(
             "deconvolve", data.Y, data.psfs, cfg=cfg, mesh=mesh)
         extra = dict(stamps=int(data.Y.shape[0]),
-                     stamp=int(data.Y.shape[-1]), n_scales=4, **timing,
+                     stamp=int(data.Y.shape[-1]), n_scales=4, **ran,
                      data_leaf_devices=leaf_devices(sol.bundle))
         check_operator(extra, xs[0], sol.bundle.data["HX"], data.psfs)
         del sol
@@ -263,10 +230,10 @@ class Smoke:
         """Returns ([NRMSE trajectory], dictionaries, extra, hlo)."""
         from repro.imaging.scdl import SCDLConfig
         cfg = SCDLConfig(n_atoms=512, max_iter=ITERS)
-        sol, xs, timing = self.timed_solve("scdl", S_h, S_l, cfg=cfg,
+        sol, xs, ran = self.solve("scdl", S_h, S_l, cfg=cfg,
                                            mesh=mesh)
         extra = dict(P=S_h.shape[0], M=S_l.shape[0], A=512,
-                     K=S_h.shape[1], **timing,
+                     K=S_h.shape[1], **ran,
                      nrmse_first_last=[sol.log.costs[0], sol.log.costs[-1]],
                      data_leaf_devices=leaf_devices(sol.bundle))
         costs = [np.asarray(sol.log.costs)]
@@ -298,16 +265,12 @@ class Smoke:
                     options=options)) for d in pops]
                 return [await svc.result(r.id, timeout=900) for r in recs]
 
-        self.clock.take()
-        t0 = time.perf_counter()
         recs = asyncio.run(drive())
-        wall = time.perf_counter() - t0
-        compile_s = self.clock.take()
         hlo = self.chunk_hlo(True)
         done = all(r.status == "done" for r in recs)
         xs = ([np.asarray(r.solution.x) for r in recs] if done else [])
         refs = [self.reference(lambda d=d: np.asarray(
-            self.timed_solve("deconvolve", d.Y, d.psfs, cfg=cfg,
+            self.solve("deconvolve", d.Y, d.psfs, cfg=cfg,
                              **options)[0].x)) for d in pops]
         self.chunk_hlo(False)
         extra = dict(
@@ -315,10 +278,7 @@ class Smoke:
                             for r in recs),
             requests=len(recs), status=[r.status for r in recs],
             errors=[r.error for r in recs if r.error],
-            batch_sizes=[r.batch_size for r in recs],
-            compile_s=compile_s, wall_s=wall,
-            steady_s_per_iter=(steady_per_iter(recs[0].solution.log,
-                                               CHUNK) if done else None))
+            batch_sizes=[r.batch_size for r in recs])
         return xs or [np.full(1, np.nan)], refs[:len(xs) or 1], extra, hlo
 
     def completion(self):
@@ -338,13 +298,13 @@ class Smoke:
         # stays at 0.4 recovery error after 24 steps)
         cfg = CompletionConfig(rank=COMPLETION_RANK, oversample=
                                COMPLETION_RANK, max_iter=ITERS, tol=0.0)
-        sol, xs, timing = self.timed_solve("lowrank", Y, M, cfg=cfg)
+        sol, xs, ran = self.solve("lowrank", Y, M, cfg=cfg)
         del sol
         hidden = np.asarray(M) == 0
         Y = np.asarray(Y)
         err = float(np.linalg.norm((xs[0] - Y)[hidden])
                     / np.linalg.norm(Y[hidden]))
-        extra = dict(n=n, p=p, true_rank=r, observed=0.6, **timing)
+        extra = dict(n=n, p=p, true_rank=r, observed=0.6, **ran)
         self.svt_check(extra, n, p, COMPLETION_RANK, 2 * COMPLETION_RANK)
         return xs, err, extra, self.chunk_hlo(True)
 

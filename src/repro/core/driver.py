@@ -145,6 +145,26 @@ def percentiles(values, qs=(50, 90, 99)) -> Dict[str, float]:
             float(np.percentile(vals, q)) for q in qs}
 
 
+def _chunk_span(part: str, start: int, **args):
+    """Host span of one chunk in the profiler's trace: ``repro.chunk``
+    (``part=""``, from dispatch to the end of the chunk's host work) or
+    ``repro.chunk.<part>`` inside it.  Every span of a chunk carries
+    ``chunk=<start iteration>``; with no profiler running a span costs
+    about a microsecond."""
+    name = f"repro.chunk.{part}" if part else "repro.chunk"
+    return jax.profiler.TraceAnnotation(name, chunk=int(start), **args)
+
+
+def _costs_to_host(trace, start: int) -> np.ndarray:
+    """The chunk's cost buffer on the host: the wait for the device
+    (``repro.chunk.wait``), then the copy (``repro.chunk.fetch``)."""
+    costs = trace["cost"] if isinstance(trace, dict) else trace
+    with _chunk_span("wait", start):
+        costs = jax.block_until_ready(costs)
+    with _chunk_span("fetch", start):
+        return np.asarray(jax.device_get(costs))
+
+
 @dataclass
 class RunLog:
     costs: List[float] = field(default_factory=list)
@@ -390,14 +410,14 @@ class IterativeDriver:
         resilience supervisor can retry (the ``dispatch`` chaos fault
         point lives here, so injected failures tick per attempt)."""
         _chaos.maybe_raise("dispatch", step=i)
-        if self._cost_per_chunk or self._skips_cost:
-            data, rep, last, trace = self._scan_step(k)(
-                data, rep, np.int32(i), last)
-        else:
-            data, rep, trace = self._scan_step(k)(data, rep, np.int32(i))
-        costs = trace["cost"] if isinstance(trace, dict) else trace
-        costs = np.asarray(jax.device_get(jax.block_until_ready(costs)))
-        return data, rep, last, costs
+        with _chunk_span("dispatch", i):
+            if self._cost_per_chunk or self._skips_cost:
+                data, rep, last, trace = self._scan_step(k)(
+                    data, rep, np.int32(i), last)
+            else:
+                data, rep, trace = self._scan_step(k)(data, rep,
+                                                      np.int32(i))
+        return data, rep, last, _costs_to_host(trace, i)
 
     def _run_chunked(self, start_iter: int) -> Bundle:
         data, rep = self.bundle.data, self.bundle.replicated
@@ -415,69 +435,76 @@ class IterativeDriver:
             k = min(self.chunk, self.max_iter - i)
             first_call = k not in compiled_ks
             compiled_ks.add(k)
-            t0 = time.perf_counter()
-            if sup is not None:
-                sup.begin_chunk(data, rep, last, i, len(self.log.costs))
-                try:
-                    data, rep, last, costs = sup.dispatch(
-                        self._dispatch_chunk, data, rep, last, i, k)
-                    if _chaos.is_active():  # silent-corruption injector
+            with _chunk_span("", i, first_call=int(first_call)):
+                t0 = time.perf_counter()
+                if sup is not None:
+                    sup.begin_chunk(data, rep, last, i,
+                                    len(self.log.costs))
+                    try:
+                        data, rep, last, costs = sup.dispatch(
+                            self._dispatch_chunk, data, rep, last, i, k)
+                        if _chaos.is_active():  # silent-corruption injector
+                            data = _chaos.poison_tree("carry_nan", data,
+                                                      step=i)
+                        sup.validate(data, rep, costs, i + k - 1)
+                    except DivergenceError as e:
+                        sup.report.wall_time_lost_s += \
+                            time.perf_counter() - t0
+                        data, rep, last, i = sup.rollback(e, self.log)
+                        ema = None  # timings across a rollback don't compare
+                        continue
+                else:
+                    data, rep, last, costs = self._dispatch_chunk(
+                        data, rep, last, i, k)
+                    if _chaos.is_active():
                         data = _chaos.poison_tree("carry_nan", data,
                                                   step=i)
-                    sup.validate(data, rep, costs, i + k - 1)
-                except DivergenceError as e:
-                    sup.report.wall_time_lost_s += \
-                        time.perf_counter() - t0
-                    data, rep, last, i = sup.rollback(e, self.log)
-                    ema = None  # timings across a rollback don't compare
-                    continue
-            else:
-                data, rep, last, costs = self._dispatch_chunk(
-                    data, rep, last, i, k)
-                if _chaos.is_active():
-                    data = _chaos.poison_tree("carry_nan", data, step=i)
-            dt = time.perf_counter() - t0
-            if self.checks:
-                _checks.assert_costs_finite(
-                    costs, f"chunk ending at iteration {i + k - 1}")
-                _checks.assert_all_finite(
-                    {"data": data, "replicated": rep},
-                    f"state after iteration {i + k - 1}")
-            self.log.times.extend([dt / k] * k)
-            self.log.costs.extend(float(c) for c in np.ravel(costs))
-            # a chunk length's first dispatch includes XLA compilation
-            # (e.g. the shorter tail program) — keep it out of the
-            # straggler watchdog and its EMA
-            if not first_call:
-                if ema is not None and dt > self.straggler_factor * ema:
-                    self.log.straggler_steps.append(i)
-                    if self.checkpoint_fn is not None:
-                        self.checkpoint_fn(
-                            self.bundle.with_data(data, replicated=rep),
-                            i + k - 1)
-                ema = dt if ema is None else 0.9 * ema + 0.1 * dt
-            if (self.checkpoint_every and self.checkpoint_fn is not None
-                    and (i + k) // self.checkpoint_every
-                    > i // self.checkpoint_every):
-                self.checkpoint_fn(
-                    self.bundle.with_data(data, replicated=rep), i + k - 1)
-            i += k
-            # a local verdict, not `converged_at is not None`: a rerun of
-            # a warmed driver (benchmarks' timed_round) must not break on
-            # a previous run's convergence record
-            conv = self._converged()
-            if conv:
-                self.log.converged_at = i - 1
-            if self.progress_fn is not None:
-                ctl = self.progress_fn(self._progress_event(i - k, k, dt))
-                # only a dict return is a control signal — callbacks
-                # that happen to return something else (a logging
-                # listcomp, an appended list) must stay inert
-                if isinstance(ctl, dict) and ctl.get("stop"):
-                    self.log.cancelled_at = i - 1
+                dt = time.perf_counter() - t0
+                with _chunk_span("host", i):
+                    if self.checks:
+                        _checks.assert_costs_finite(
+                            costs, f"chunk ending at iteration {i + k - 1}")
+                        _checks.assert_all_finite(
+                            {"data": data, "replicated": rep},
+                            f"state after iteration {i + k - 1}")
+                    self.log.times.extend([dt / k] * k)
+                    self.log.costs.extend(float(c) for c in np.ravel(costs))
+                    # a chunk length's first dispatch includes XLA
+                    # compilation (e.g. the shorter tail program) — keep
+                    # it out of the straggler watchdog and its EMA
+                    if not first_call:
+                        if ema is not None and \
+                                dt > self.straggler_factor * ema:
+                            self.log.straggler_steps.append(i)
+                            if self.checkpoint_fn is not None:
+                                self.checkpoint_fn(self.bundle.with_data(
+                                    data, replicated=rep), i + k - 1)
+                        ema = dt if ema is None else 0.9 * ema + 0.1 * dt
+                    if (self.checkpoint_every
+                            and self.checkpoint_fn is not None
+                            and (i + k) // self.checkpoint_every
+                            > i // self.checkpoint_every):
+                        self.checkpoint_fn(self.bundle.with_data(
+                            data, replicated=rep), i + k - 1)
+                    # a local verdict, not `converged_at is not None`: a
+                    # rerun of a warmed driver (benchmarks' timed_round)
+                    # must not break on a previous run's convergence record
+                    conv = self._converged()
+                i += k
+                if conv:
+                    self.log.converged_at = i - 1
+                if self.progress_fn is not None:
+                    with _chunk_span("progress", i - k):
+                        ctl = self.progress_fn(
+                            self._progress_event(i - k, k, dt))
+                    # only a dict return is a control signal — callbacks
+                    # that happen to return something else (a logging
+                    # listcomp, an appended list) must stay inert
+                    if isinstance(ctl, dict) and ctl.get("stop"):
+                        self.log.cancelled_at = i - 1
+                        break
+                if conv:
                     break
-            if conv:
-                break
         # accumulate across reruns of a warmed driver, mirroring the
         # batched driver's per-instance counter
         self.log.iters_run = (self.log.iters_run or 0) + (i - start_iter)
@@ -701,11 +728,10 @@ class BatchedDriver:
     # -------------------------------------------------------- dispatch
     def _dispatch_chunk(self, state, mask, i: int, k: int):
         _chaos.maybe_raise("dispatch", step=i)
-        state, trace = self._scan_step(k)(
-            state, self.bundle.replicated, mask, np.int32(i))
-        costs = trace["cost"] if isinstance(trace, dict) else trace
-        costs = np.asarray(jax.device_get(jax.block_until_ready(costs)))
-        return state, costs                      # costs: (k, B_current)
+        with _chunk_span("dispatch", i):
+            state, trace = self._scan_step(k)(
+                state, self.bundle.replicated, mask, np.int32(i))
+        return state, _costs_to_host(trace, i)   # costs: (k, B_current)
 
     def _log_chunk(self, costs, dt: float, i: int, k: int) -> None:
         per = dt / max(k, 1)
@@ -872,50 +898,62 @@ class BatchedDriver:
         if self.options.resilience is not None:
             sup = _BatchSupervisor(self.options.resilience, self)
         i = start_iter
+        # the program compiles once per (chunk length, batch size):
+        # re-compaction's smaller batches compile too
+        compiled = set()
         while i < self.max_iter and bool(self.active.any()):
             k = min(self.chunk, self.max_iter - i)
+            first_call = (k, len(self.slots)) not in compiled
+            compiled.add((k, len(self.slots)))
             mask = jnp.asarray(self.active[self.slots])
-            t0 = time.perf_counter()
-            if sup is not None:
-                sup.begin_chunk(i)
-                try:
-                    state, costs = sup.dispatch(
-                        self._dispatch_chunk, self.state, mask, i, k)
+            with _chunk_span("", i, first_call=int(first_call)):
+                t0 = time.perf_counter()
+                if sup is not None:
+                    sup.begin_chunk(i)
+                    try:
+                        state, costs = sup.dispatch(
+                            self._dispatch_chunk, self.state, mask, i, k)
+                        if _chaos.is_active():
+                            state = dict(state, d=_chaos.poison_tree(
+                                "carry_nan", state["d"], step=i))
+                        sup.validate(state, costs, i + k - 1)
+                    except DivergenceError as e:
+                        sup.report.wall_time_lost_s += \
+                            time.perf_counter() - t0
+                        i = sup.rollback(e)
+                        continue
+                else:
+                    state, costs = self._dispatch_chunk(
+                        self.state, mask, i, k)
                     if _chaos.is_active():
                         state = dict(state, d=_chaos.poison_tree(
                             "carry_nan", state["d"], step=i))
-                    sup.validate(state, costs, i + k - 1)
-                except DivergenceError as e:
-                    sup.report.wall_time_lost_s += \
-                        time.perf_counter() - t0
-                    i = sup.rollback(e)
-                    continue
-            else:
-                state, costs = self._dispatch_chunk(
-                    self.state, mask, i, k)
-                if _chaos.is_active():
-                    state = dict(state, d=_chaos.poison_tree(
-                        "carry_nan", state["d"], step=i))
-            self.state = state
-            self.bundle = self.bundle.with_data(state)
-            dt = time.perf_counter() - t0
-            if self.checks:
-                _checks.assert_costs_finite(
-                    costs, f"bucket chunk ending at iteration {i + k - 1}")
-                _checks.assert_all_finite(
-                    {"data": state["d"], "replicated": state["r"]},
-                    f"bucket state after iteration {i + k - 1}")
-            self._log_chunk(costs, dt, i, k)
-            if (self.checkpoint_every and self.checkpoint_fn is not None
-                    and (i + k) // self.checkpoint_every
-                    > i // self.checkpoint_every):
-                self.checkpoint_fn(self.snapshot_payload(), i + k - 1)
-            i += k
-            if self.progress_fn is not None:
-                ctl = self.progress_fn(self._progress_event(i - k, k, dt))
-                if isinstance(ctl, dict):
-                    self._apply_control(ctl, i - 1)
-            self._maybe_recompact()
+                self.state = state
+                self.bundle = self.bundle.with_data(state)
+                dt = time.perf_counter() - t0
+                with _chunk_span("host", i):
+                    if self.checks:
+                        _checks.assert_costs_finite(
+                            costs,
+                            f"bucket chunk ending at iteration {i + k - 1}")
+                        _checks.assert_all_finite(
+                            {"data": state["d"], "replicated": state["r"]},
+                            f"bucket state after iteration {i + k - 1}")
+                    self._log_chunk(costs, dt, i, k)
+                    if (self.checkpoint_every
+                            and self.checkpoint_fn is not None
+                            and (i + k) // self.checkpoint_every
+                            > i // self.checkpoint_every):
+                        self.checkpoint_fn(self.snapshot_payload(),
+                                           i + k - 1)
+                i += k
+                if self.progress_fn is not None:
+                    with _chunk_span("progress", i - k):
+                        ctl = self.progress_fn(
+                            self._progress_event(i - k, k, dt))
+                    if isinstance(ctl, dict):
+                        self._apply_control(ctl, i - 1)
+                self._maybe_recompact()
         if sup is not None:
             self.recovery = sup.finalize()
         return self

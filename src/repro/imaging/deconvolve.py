@@ -18,6 +18,14 @@ itself runs chunked on-device (``chunk`` iterations per dispatch,
 DESIGN.md §12); ``make_light_step_fn`` is the cost-free step used to
 skip the objective evaluation off the ``cost_every`` grid.
 
+Each layer of the iteration runs under a ``jax.named_scope`` — the
+HLO ``op_name`` path the profiler's device trace names it by:
+``condat`` (the primal/dual updates and their layout glue),
+``psf_operator`` (``psf.H_fp``/``Ht_fp``/``conv_pair_f``), ``starlet``
+(the batched transforms), ``svt`` (the randomized SVT) and
+``objective`` (the cost and its psums).  The innermost scope names an
+op; scopes change metadata only, not the compiled program.
+
 The workload is declared once as :class:`DeconvolutionProblem`
 (registered under ``"deconvolve"``, DESIGN.md §14) and run through the
 generic ``repro.core.problem.solve`` entry point; the original
@@ -81,6 +89,7 @@ def build_bundle(Y, psfs, cfg: SolverConfig, mesh=None,
     return bundle, {"tau": tau, "sig": sig}
 
 
+@jax.named_scope("condat")
 def _sparse_update(d, rep, cfg: SolverConfig):
     """Steps 7-8 (sparse): primal + dual updates, no cost.  Returns the
     new data blocks plus the scale-major (W, CX_new) the objective
@@ -98,6 +107,7 @@ def _sparse_update(d, rep, cfg: SolverConfig):
                 HX=psf_op.H_fp(X_new, d["psf_fp"])), (W, CX_new)
 
 
+@jax.named_scope("condat")
 def _lowrank_update(d, rep, axes, cfg: SolverConfig):
     """Steps 7-8 (low-rank): primal update + distributed randomized SVT."""
     U, sig = d["Xd"], rep["sig"]
@@ -120,20 +130,23 @@ def make_step_fn(cfg: SolverConfig):
     def step(d, rep, axes):
         if cfg.mode == "sparse":
             d_new, (W, CX_new) = _sparse_update(d, rep, cfg)
-            cost_part = data_cost_from(d_new["HX"], d["Y"]) + \
-                sparse_reg_cost(CX_new, W)
-            if axes:
-                cost_part = jax.lax.psum(cost_part, axes)
+            with jax.named_scope("objective"):
+                cost_part = data_cost_from(d_new["HX"], d["Y"]) + \
+                    sparse_reg_cost(CX_new, W)
+                if axes:
+                    cost_part = jax.lax.psum(cost_part, axes)
             return d_new, {"cost": cost_part}
         d_new = _lowrank_update(d, rep, axes, cfg)
-        # nuclear-norm cost via the same range finder
-        nuc = lr.nuclear_norm_rf(
-            d_new["Xp"].reshape(d_new["Xp"].shape[0], -1),
-            rep["omega"], axes)
-        cost_part = data_cost_from(d_new["HX"], d["Y"])
-        if axes:
-            cost_part = jax.lax.psum(cost_part, axes)
-        return d_new, {"cost": cost_part + cfg.lam * nuc}
+        with jax.named_scope("objective"):
+            # nuclear-norm cost via the same range finder
+            nuc = lr.nuclear_norm_rf(
+                d_new["Xp"].reshape(d_new["Xp"].shape[0], -1),
+                rep["omega"], axes)
+            cost_part = data_cost_from(d_new["HX"], d["Y"])
+            if axes:
+                cost_part = jax.lax.psum(cost_part, axes)
+            cost_part = cost_part + cfg.lam * nuc
+        return d_new, {"cost": cost_part}
 
     return step
 
@@ -159,6 +172,7 @@ def make_cost_fn(cfg: SolverConfig):
     scan body runs only the cost-free step and this evaluates once per
     dispatch, off the carried forward model ``HX``."""
 
+    @jax.named_scope("objective")
     def cost(d, rep, axes):
         data_part = data_cost_from(d["HX"], d["Y"])
         if cfg.mode == "sparse":

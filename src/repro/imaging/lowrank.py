@@ -67,6 +67,7 @@ def _orthonormalize(y, axes, eps):
     return _mm(y, evecs * scale[None, :])
 
 
+@jax.named_scope("svt")
 def randomized_svt_local(a_local: jax.Array, omega: jax.Array, thresh,
                          axes=None, eps: float = 1e-6) -> jax.Array:
     """SVT of the row-sharded matrix from inside a shard_map/bundle_map.

@@ -193,17 +193,20 @@ def Ht_f(Y: jax.Array, kf: jax.Array) -> jax.Array:
 
 # ------------------------------------------------- paired convolution
 
+@jax.named_scope("psf_operator")
 def H_fp(X: jax.Array, kf_pair: jax.Array) -> jax.Array:
     """Forward convolution off the carried pair (no conj, no kernel FFT)."""
     return convolve_f(X, kf_pair[..., 0, :, :])
 
 
+@jax.named_scope("psf_operator")
 def Ht_fp(Y: jax.Array, kf_pair: jax.Array) -> jax.Array:
     """Adjoint convolution off the carried pair — the conjugate spectrum
     is precomputed, so this is one rfft2 -> multiply -> irfft2."""
     return convolve_f(Y, kf_pair[..., 1, :, :])
 
 
+@jax.named_scope("psf_operator")
 def conv_pair_f(A: jax.Array, B: jax.Array, kf_pair: jax.Array
                 ) -> Tuple[jax.Array, jax.Array]:
     """(H(A), Ht(B)) for two independent operands in ONE batched FFT

@@ -48,6 +48,7 @@ def smooth(imgs, *, scale: int, use_kernel: bool = True,
         requested_interpret=interpret)
 
 
+@jax.named_scope("starlet")
 def decompose(imgs, n_scales: int, **kw):
     """Batched starlet analysis via the kernel: (N,H,W) -> (J+1,N,H,W)."""
     scales = []
@@ -60,11 +61,13 @@ def decompose(imgs, n_scales: int, **kw):
     return jnp.stack(scales)
 
 
+@jax.named_scope("starlet")
 def forward(imgs, n_scales: int, **kw):
     """Batched Phi: detail scales only, (N,H,W) -> (J,N,H,W)."""
     return decompose(imgs, n_scales, **kw)[:-1]
 
 
+@jax.named_scope("starlet")
 def adjoint(coeffs, n_scales: int, **kw):
     """Batched Phi^T: (J,N,H,W) -> (N,H,W).
 
