@@ -72,9 +72,14 @@ def vmem_rows(tail, live: int, cap: int = 128) -> int:
 
 def pad_leading(arrays, block: int):
     """Zero-pad a shared leading axis to a whole number of ``block``
-    rows (pad rows are inert for the kernels using this: they produce
-    pad rows or contribute zero to accumulators).  Returns the padded
-    list and the padded length."""
+    rows.  Returns the padded list and the padded length.
+
+    ``dict_outer`` needs it: it accumulates Grams over rows, so every
+    row a block reads must be real or zero, and a ragged last block
+    would read undefined rows into the sum.  ``admm_elwise`` uses it
+    too (its pad rows produce pad rows).  The purely per-row
+    ``condat_elwise`` passes use a ragged last block instead and copy
+    nothing."""
     n = arrays[0].shape[0]
     pad = -n % block
     if pad:

@@ -18,8 +18,14 @@ replicated state), so they enter through SMEM rather than being baked
 into the kernel body like ``admm_elwise``'s static ADMM constants.
 
 Grids are 1-D over the flattened leading (record/scale) axis,
-embarrassingly parallel; non-dividing leading sizes zero-pad up to a
-whole block (pad rows produce pad rows; the caller slices them off).
+embarrassingly parallel, and run on the caller's arrays at their true
+length: ``pl.cdiv(rows, block)`` steps, the last block ragged where the
+block does not divide the rows.  Each row's output depends only on that
+row, so the rows a ragged block reads past the end (undefined) only
+produce writes that Pallas drops.  Each pass updates its first array
+operand in place (X becomes X_new, U the clamped U): no padded copy in,
+no sliced copy out.  Where the caller still holds that operand, XLA
+copies it first, so the alias never changes a live array.
 Block sizes default to what the scoped-VMEM budget allows for the
 stamp size (``kernels.common.vmem_rows``): a 41x41 stamp pads to a
 48x128 tile, and inside a solver's chunk program Mosaic keeps about ten
@@ -33,7 +39,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import auto_interpret, pad_leading, vmem_rows
+from repro.kernels.common import auto_interpret, vmem_rows
 
 # fp32 blocks each kernel body keeps live in VMEM (see vmem_rows)
 _LIVE_BLOCKS = 10
@@ -71,54 +77,52 @@ def _scalar_spec():
 
 def condat_primal_fwd(X, U_adj, grad, tau, *, with_xbar: bool = False,
                       block_n=None, interpret=None):
-    """X/U_adj/grad: (N, S, S); tau scalar.  Returns X_new (and X_bar)."""
+    """X/U_adj/grad: (N, S, S); tau scalar.  Returns X_new (and X_bar);
+    X_new takes X's buffer."""
     if interpret is None:
         interpret = auto_interpret()
     n, s = X.shape[0], X.shape[-1]
     if block_n is None:
         block_n = vmem_rows((s, s), _LIVE_BLOCKS)
     block_n = min(block_n, n)
-    ins, n_full = pad_leading([X, U_adj, grad], block_n)
     tau = jnp.asarray(tau, jnp.float32).reshape((1, 1))
 
     blk = pl.BlockSpec((block_n, s, s), lambda i: (i, 0, 0))
-    shape = jax.ShapeDtypeStruct((n_full, s, s), X.dtype)
+    shape = jax.ShapeDtypeStruct(X.shape, X.dtype)
     kernel = _primal_xbar_kernel if with_xbar else _primal_kernel
-    out = pl.pallas_call(
+    return pl.pallas_call(
         kernel,
-        grid=(n_full // block_n,),
+        grid=(pl.cdiv(n, block_n),),
         in_specs=[_scalar_spec(), blk, blk, blk],
         out_specs=[blk, blk] if with_xbar else blk,
         out_shape=[shape, shape] if with_xbar else shape,
+        input_output_aliases={1: 0},
         interpret=interpret,
         name="condat_elwise_primal",
-    )(tau, *ins)
-    if with_xbar:
-        return out[0][:n], out[1][:n]
-    return out[:n]
+    )(tau, X, U_adj, grad)
 
 
 def condat_dual_fwd(U, C_new, C_old, W, sig, *, block_m=None,
                     interpret=None):
-    """U/C_new/C_old: (M, S, S); W: (M, 1, 1); sig scalar."""
+    """U/C_new/C_old: (M, S, S); W: (M, 1, 1); sig scalar.  The clamped
+    dual takes U's buffer."""
     if interpret is None:
         interpret = auto_interpret()
     m, s = U.shape[0], U.shape[-1]
     if block_m is None:
         block_m = vmem_rows((s, s), _LIVE_BLOCKS)
     block_m = min(block_m, m)
-    ins, m_full = pad_leading([U, C_new, C_old, W], block_m)
     sig = jnp.asarray(sig, jnp.float32).reshape((1, 1))
 
     blk = pl.BlockSpec((block_m, s, s), lambda i: (i, 0, 0))
-    out = pl.pallas_call(
+    return pl.pallas_call(
         _dual_kernel,
-        grid=(m_full // block_m,),
+        grid=(pl.cdiv(m, block_m),),
         in_specs=[_scalar_spec(), blk, blk, blk,
                   pl.BlockSpec((block_m, 1, 1), lambda i: (i, 0, 0))],
         out_specs=blk,
-        out_shape=jax.ShapeDtypeStruct((m_full, s, s), U.dtype),
+        out_shape=jax.ShapeDtypeStruct(U.shape, U.dtype),
+        input_output_aliases={1: 0},
         interpret=interpret,
         name="condat_elwise_dual",
-    )(sig, *ins)
-    return out[:m]
+    )(sig, U, C_new, C_old, W)

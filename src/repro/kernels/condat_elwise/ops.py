@@ -16,7 +16,12 @@ at Python level; both implementations underneath stay jitted.
 Both wrappers accept arbitrary leading batch shape: ``condat_dual``
 flattens the (scale, record) leading axes of the dual stack into the
 kernel's 1-D grid axis (the weight column broadcasts per leading index,
-shaped (..., 1, 1) like ``condat.weight_matrix`` emits).
+shaped (..., 1, 1) like ``condat.weight_matrix`` emits).  The kernels
+run on the flattened arrays at their true length (a ragged last block)
+and write X_new / the clamped U into the first operand's buffer, so
+nothing is padded in or sliced out.  These jitted wrappers donate
+nothing: a caller's array stays unchanged, and inside a solver's chunk
+program the update reuses the carried buffer.
 """
 from __future__ import annotations
 

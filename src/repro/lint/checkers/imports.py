@@ -1,7 +1,7 @@
 """RPL601 noncanonical-import: kernel-family shared helpers must be
 imported from ``repro.kernels.common``, their canonical home.
 
-``auto_interpret`` / ``pad_leading`` are re-exported by every family's
+``auto_interpret`` / ``pad_leading`` are re-exported by the families'
 ``kernel.py`` for historical reasons; importing them *through* a family
 module couples unrelated families (condat ops depending on condat
 kernel internals for a backend-selection helper) and means an import

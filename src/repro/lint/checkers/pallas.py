@@ -11,7 +11,8 @@ RPL203 ref-parity          : a kernel family's ``ref.py`` oracle and
                              missing entirely.
 
 The grid/BlockSpec check leans on this codebase's kernel idiom: 1-D (or
-n-D) grids of the form ``grid=(padded // block, ...)`` with
+n-D) grids of the form ``grid=(padded // block, ...)`` or
+``grid=(pl.cdiv(rows, block), ...)`` (a ragged last block) with
 ``pl.BlockSpec((block, ...), lambda i, ...: (i, 0, 0))``.  For each grid
 axis it finds the position where the lambda parameter appears in the
 index map's return tuple and requires the block shape at that position
@@ -68,11 +69,15 @@ def _grid_axes(grid_node) -> Optional[List]:
 
 
 def _block_divisor(axis_node) -> Optional[str]:
-    """``padded // block`` -> "block" (the name the block shape must
-    use); None when the axis expression has another shape."""
+    """``padded // block`` or ``pl.cdiv(rows, block)`` -> "block" (the
+    name the block shape must use); None when the axis expression has
+    another shape."""
     if isinstance(axis_node, ast.BinOp) and \
             isinstance(axis_node.op, ast.FloorDiv):
         return dotted(axis_node.right)
+    if isinstance(axis_node, ast.Call) and len(axis_node.args) == 2 and \
+            (dotted(axis_node.func) or "").split(".")[-1] == "cdiv":
+        return dotted(axis_node.args[1])
     return None
 
 

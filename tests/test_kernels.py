@@ -205,8 +205,10 @@ from repro.kernels.condat_elwise.ops import condat_dual, condat_primal
 from repro.kernels.condat_elwise.ref import (condat_dual_ref,
                                              condat_primal_ref)
 
-# (100, ...) / (130, ...) exercise the non-block-aligned zero-pad
-CP_CASES = [(100, 41), (130, 21), (16, 41), (256, 33)]
+# (100, ...) / (130, ...) exercise the ragged last block; 49 rows of
+# 41x41 are one past the derived 48-row block, so the last block holds
+# a single real row
+CP_CASES = [(100, 41), (130, 21), (16, 41), (256, 33), (49, 41)]
 
 
 @pytest.mark.parametrize("case", CP_CASES)
@@ -231,7 +233,8 @@ def test_condat_primal(case, dtype):
                                np.asarray(rb, np.float32), **_tol(dtype))
 
 
-@pytest.mark.parametrize("case", [(3, 100, 41), (4, 37, 21), (2, 130, 33)])
+@pytest.mark.parametrize("case", [(3, 100, 41), (4, 37, 21), (2, 130, 33),
+                                  (1, 49, 41)])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_condat_dual(case, dtype):
     """Fused over-relaxation + dual clamp over the (J, n, S, S) stack
@@ -247,6 +250,28 @@ def test_condat_dual(case, dtype):
     ref = condat_dual_ref(U, Cn, Co, W, 0.47)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32), **_tol(dtype))
+
+
+def test_condat_kernels_leave_caller_arrays_unchanged():
+    """Each pass writes its first operand's buffer (X, U); an array the
+    caller still holds must come back as it went in."""
+    J, N, S = 2, 100, 41
+    X, Ua, g = (jax.random.normal(jax.random.fold_in(KEY, 45 + i),
+                                  (N, S, S)) for i in range(3))
+    U, Cn, Co = (jax.random.normal(jax.random.fold_in(KEY, 48 + i),
+                                   (J, N, S, S)) for i in range(3))
+    W = jax.random.uniform(jax.random.fold_in(KEY, 51), (J, N, 1, 1))
+    held = [np.array(a) for a in (X, Ua, g, U, Cn, Co, W)]
+    xn = condat_primal(X, Ua, g, 0.31, use_kernel=True, interpret=True)
+    xn2, _ = condat_primal(X, Ua, g, 0.31, with_xbar=True,
+                           use_kernel=True, interpret=True)
+    un = condat_dual(U, Cn, Co, W, 0.47, use_kernel=True, interpret=True)
+    for before, after in zip(held, (X, Ua, g, U, Cn, Co, W)):
+        np.testing.assert_array_equal(np.asarray(after), before)
+    # the updates did happen
+    assert not np.array_equal(np.asarray(xn), held[0])
+    np.testing.assert_array_equal(np.asarray(xn2), np.asarray(xn))
+    assert not np.array_equal(np.asarray(un), held[3])
 
 
 def test_condat_dual_matches_unfused_formulation():

@@ -157,6 +157,25 @@ def test_rpl201_input_block_ignores_grid_index(tmp_path):
     assert len(hits) == 1 and "same input block" in hits[0].message
 
 
+def test_rpl201_cdiv_grid(tmp_path):
+    # a ragged-last-block grid steps in units of cdiv's divisor: the
+    # consistent spec passes, the one blocked by another name is caught
+    found = _lint(tmp_path, _PALLAS_HEADER + """
+        def fwd(x, n, block_n, block_m, interpret=False):
+            return pl.pallas_call(
+                kernel,
+                grid=(pl.cdiv(n, block_n),),
+                in_specs=[pl.BlockSpec((block_n, 4), lambda i: (i, 0)),
+                          pl.BlockSpec((block_m, 4), lambda i: (i, 0))],
+                out_specs=pl.BlockSpec((block_n, 4), lambda i: (i, 0)),
+                interpret=interpret,
+            )(x, x)
+    """)
+    hits = _only(found, "RPL201")
+    assert len(hits) == 1 and hits[0].line == 12
+    assert "'block_n'" in hits[0].message and "'block_m'" in hits[0].message
+
+
 def test_rpl201_negative_idiomatic_and_accumulator(tmp_path):
     # the repo idiom: matching divisor/block names, plus an accumulator
     # out_spec pinned to one block (legit on TPU's sequential grid)
